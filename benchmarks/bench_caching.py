@@ -183,6 +183,8 @@ def to_json_summary(results: Dict, policies: Sequence[str],
 
 
 def main(argv: Optional[Sequence[str]] = None) -> None:
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--policy", default=",".join(POLICIES),
                     help="comma-separated registered policy combos "

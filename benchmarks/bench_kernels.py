@@ -287,4 +287,6 @@ def run(print_rows: bool = True, out_json: Optional[str] = None):
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     run(out_json="BENCH_kernels.json")

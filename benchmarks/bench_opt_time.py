@@ -42,4 +42,6 @@ def run(print_rows: bool = True):
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     run()

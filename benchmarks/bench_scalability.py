@@ -529,6 +529,8 @@ def merge_json(path: str, backends_rows: Optional[List[Dict]] = None,
 
 def main(argv: Optional[Sequence[str]] = None) -> None:
     """CLI: Fig. 6 + both executed-join sections, JSON-merged."""
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--n-queries", type=int, default=30,
                     help="workload length of the backend/MQO sections "

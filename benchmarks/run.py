@@ -26,6 +26,8 @@ SECTIONS = {
 
 
 def main() -> None:
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     wanted = [a for a in sys.argv[1:] if a in SECTIONS] or list(SECTIONS)
     print("name,us_per_call,derived")
     for key in wanted:

@@ -16,6 +16,8 @@ N_NODES = 4
 
 
 def main():
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     print("generating a skewed PTF-like sparse array (12 files)...")
     files = make_ptf_files(n_files=12, cells_per_file_mean=2000, seed=5)
     catalog, data = build_catalog(files, tempfile.mkdtemp(), "fits",

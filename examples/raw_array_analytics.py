@@ -18,6 +18,8 @@ N_NODES = 3
 
 
 def main():
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     files = make_ptf_files(n_files=6, cells_per_file_mean=1200, seed=9)
     catalog, data = build_catalog(files, tempfile.mkdtemp(), "hdf5",
                                   n_nodes=N_NODES)

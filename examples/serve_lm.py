@@ -15,6 +15,8 @@ from repro.serve.engine import Request, ServingEngine
 
 
 def main():
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     cfg = reduced(get("qwen1.5-0.5b"), d_model=64, n_periods=2, vocab=512)
     params = init_params(cfg, jax.random.PRNGKey(0))
     rng = np.random.default_rng(1)

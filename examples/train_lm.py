@@ -10,6 +10,8 @@ import tempfile
 
 
 def main():
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=200)
     ap.add_argument("--arch", default="llama3.2-3b")

@@ -15,8 +15,8 @@ those decisions are carried out:
     device-resident buffers via ``jax.device_put``, the join plan's
     ship decisions become actual cross-device transfers with measured
     bytes and wall-clock, and each node's shape-bucketed simjoin batch
-    dispatches to the Pallas kernel (compiled when the platform
-    supports it, interpret-mode otherwise).
+    dispatches to the Pallas kernel (compiled on the chip; interpret
+    mode only on CPU devices).
 
 Both backends execute the *same* plans from the same coordinator, so
 planned byte accounting is identical by construction — the mesh backend

@@ -6,9 +6,9 @@
   * ``"pallas"`` — the batched executor: each node's chunk-pair work is
     grouped, coordinate sets are padded to the kernel's 128-wide BLOCK,
     and shape-bucketed pair batches are dispatched to the
-    ``kernels/simjoin`` Pallas kernel (interpret-mode by default, so it
-    runs on CPU CI and compiles on TPU). Its ``prune`` knob selects the
-    grid per task:
+    ``kernels/simjoin`` Pallas kernel (compiled on the chip; interpret
+    mode on CPU devices only, per ``simjoin.interpret_mode``). Its
+    ``prune`` knob selects the grid per task:
 
       - ``"dense"`` — every block pair evaluated (the parity reference);
       - ``"block"`` — coordinates spatially sorted, per-block bounding
@@ -51,7 +51,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import time
-from typing import Callable, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -69,10 +69,12 @@ JoinTask = Tuple[int, np.ndarray, np.ndarray, bool]
 
 @dataclasses.dataclass
 class PreparedBatch:
-    """One shape bucket's stacked kernel inputs, ready for dispatch.
+    """One group of a shape bucket's stacked kernel inputs, ready for
+    dispatch.
 
     ``arrays`` is ``(a_stack, b_stack)`` for the dense grid or
-    ``(a_stack, b_stack, pairs_stack)`` for the block-sparse grid;
+    ``(a_stack, b_stack, pairs_stack)`` for the block-sparse grid, each
+    ``ops.GROUP`` deep: the tasks ``idxs``, then inert padding;
     ``fn_key`` identifies the jitted entry point + static shape bucket
     (the executor memoizes the bound callable per ``fn_key`` + eps).
     The mesh backend re-places ``arrays`` onto ``node``'s device before
@@ -156,9 +158,11 @@ class PallasJoinExecutor:
     """Batched executor over the ``kernels/simjoin`` Pallas kernels.
 
     Each node's chunk-pair tasks are padded to BLOCK and bucketed by
-    padded shape and self-join mode; each bucket is dispatched as ONE
-    stacked kernel call — turning a pair-at-a-time python loop into a
-    handful of jit'd launches per query. Buckets span nodes because the
+    padded shape and self-join mode; each bucket is dispatched as
+    stacked kernel calls of ``ops.GROUP`` tasks — turning a
+    pair-at-a-time python loop into a handful of jit'd launches per
+    query, with a stack depth that never varies (so a bucket's size
+    never forces a compile). Buckets span nodes because the
     simulated backend executes every node's work on this one device; the
     mesh backend (``repro.backend.jax_mesh``) keys buckets by node and
     pins each bucket to that node's device.
@@ -197,8 +201,9 @@ class PallasJoinExecutor:
     # without re-running is a faithful redo since both are pure.
     fault_points = ("prep.build", "dispatch.kernel")
 
-    def __init__(self, interpret: bool = True, prune: str = "auto",
-                 artifacts: Optional[JoinArtifactCache] = None):
+    def __init__(self, prune: str = "auto",
+                 artifacts: Optional[JoinArtifactCache] = None,
+                 devices: Optional[Sequence[Any]] = None):
         # Imported lazily so the numpy backend never pulls in jax.
         from repro.kernels.simjoin import ops, prune as prune_mod, simjoin
         if prune not in PRUNE_MODES:
@@ -208,7 +213,9 @@ class PallasJoinExecutor:
         self._prune = prune_mod
         self._block = simjoin.BLOCK
         self._sentinel = simjoin.SENTINEL
-        self.interpret = interpret
+        # Interpret mode follows the platform of the devices this
+        # executor dispatches to (jax's default devices when None).
+        self.interpret = simjoin.interpret_mode(devices)
         self.prune = prune
         self.artifacts = (artifacts if artifacts is not None
                           else JoinArtifactCache())
@@ -317,6 +324,42 @@ class PallasJoinExecutor:
                             for i in idxs])
         return a_stack, b_stack
 
+    def _stack_block(self, tasks: Sequence[JoinTask],
+                     prepped: Dict[int, tuple], idxs: Sequence[int],
+                     plen: int) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+        """Stack one block-sparse bucket's sorted, sentinel-padded sides
+        and its pair lists laid out for ``plen`` rows (``prepped[i]`` is
+        task i's ``(sorted a, sorted b, live pairs)``)."""
+        a_stack = np.stack([self._padded_side(tasks[i][1], self._sentinel,
+                                              sorted_arr=prepped[i][0])
+                            for i in idxs])
+        b_stack = np.stack([self._padded_side(tasks[i][2], -self._sentinel,
+                                              sorted_arr=prepped[i][1])
+                            for i in idxs])
+        p_stack = np.stack([self._ops.pack_pairs_np(prepped[i][2], plen)
+                            for i in idxs])
+        return a_stack, b_stack, p_stack
+
+    def _grouped(self, node: Optional[int], same: bool, idxs: Sequence[int],
+                 fn_key: tuple, stack: Callable[[List[int]], tuple]
+                 ) -> List[PreparedBatch]:
+        """One bucket's batches: its tasks cut into groups of
+        ``ops.GROUP``, each stacked by ``stack`` and padded to that depth
+        with inert elements (sentinel coordinates, an all-invalid pair
+        list) that the kernel loop never reaches."""
+        depth = self._ops.GROUP
+        fills = (self._sentinel, -self._sentinel, 0)
+        out = []
+        for s in range(0, len(idxs), depth):
+            part = list(idxs[s:s + depth])
+            arrays = tuple(
+                np.concatenate([x, np.full((depth - len(part),)
+                                           + x.shape[1:], fill, x.dtype)])
+                for x, fill in zip(stack(part), fills))
+            out.append(PreparedBatch(node=node, same=same, idxs=part,
+                                     arrays=arrays, fn_key=fn_key))
+        return out
+
     def _batches_dense(self, tasks: Sequence[JoinTask], by_node: bool
                        ) -> Tuple[List[PreparedBatch], Dict[str, int]]:
         """Dense grid: every block pair of every bucketed task runs."""
@@ -326,12 +369,10 @@ class PallasJoinExecutor:
                                          by_node=by_node).items():
             node = key[0] if by_node else None
             same, na, nb = key[-3:]
-            a_stack, b_stack = self._stack_dense(tasks, idxs)
             total += (na // self._block) * (nb // self._block) * len(idxs)
-            batches.append(PreparedBatch(
-                node=node, same=same, idxs=list(idxs),
-                arrays=(a_stack, b_stack),
-                fn_key=("dense", same, na, nb)))
+            batches += self._grouped(
+                node, same, idxs, ("dense", same, na, nb),
+                lambda part: self._stack_dense(tasks, part))
         return batches, {"block_pairs_total": total,
                          "block_pairs_evaluated": total}
 
@@ -416,27 +457,16 @@ class PallasJoinExecutor:
         for key, idxs in block_buckets.items():
             node = key[0] if by_node else None
             same, na, nb, plen = key[-4:]
-            a_stack = np.stack([self._padded_side(tasks[i][1], self._sentinel,
-                                                  sorted_arr=prepped[i][0])
-                                for i in idxs])
-            b_stack = np.stack([self._padded_side(tasks[i][2],
-                                                  -self._sentinel,
-                                                  sorted_arr=prepped[i][1])
-                                for i in idxs])
-            p_stack = np.stack([self._prune.pad_pairs(prepped[i][2], plen)
-                                for i in idxs])
-            batches.append(PreparedBatch(
-                node=node, same=same, idxs=list(idxs),
-                arrays=(a_stack, b_stack, p_stack),
-                fn_key=("block", same, na, nb, plen)))
+            batches += self._grouped(
+                node, same, idxs, ("block", same, na, nb, plen),
+                functools.partial(self._stack_block, tasks, prepped,
+                                  plen=plen))
         for key, idxs in dense_buckets.items():
             node = key[0] if by_node else None
             same, na, nb = key[-3:]
-            a_stack, b_stack = self._stack_dense(tasks, idxs)
-            batches.append(PreparedBatch(
-                node=node, same=same, idxs=list(idxs),
-                arrays=(a_stack, b_stack),
-                fn_key=("dense", same, na, nb)))
+            batches += self._grouped(
+                node, same, idxs, ("dense", same, na, nb),
+                lambda part: self._stack_dense(tasks, part))
         stats = {"block_pairs_total": total,
                  "block_pairs_evaluated": evaluated}
         if bitmap_s is not None:
@@ -449,9 +479,11 @@ class PallasJoinExecutor:
     def dispatch(self, batch: PreparedBatch, eps: int,
                  arrays: Optional[tuple] = None):
         """Run one prepared batch through its memoized jitted entry;
-        returns the (k,) per-task match-count device array. ``arrays``
-        overrides ``batch.arrays`` with device-placed copies (the mesh
-        backend pins them to the executing node's device first)."""
+        returns the ``(ops.GROUP,)`` match-count device array, whose
+        first ``len(batch.idxs)`` entries are the batch's tasks.
+        ``arrays`` overrides ``batch.arrays`` with device-placed copies
+        (the mesh backend pins them to the executing node's device
+        first)."""
         key = batch.fn_key + (int(eps), self.interpret)
         fn = self._fn_cache.get(key)
         if fn is None:
@@ -461,7 +493,8 @@ class PallasJoinExecutor:
             fn = functools.partial(base, eps=int(eps), same=batch.same,
                                    interpret=self.interpret)
             self._fn_cache[key] = fn
-        return fn(*(arrays if arrays is not None else batch.arrays))
+        return fn(*(arrays if arrays is not None else batch.arrays),
+                  len(batch.idxs))
 
     def count_pairs(self, tasks: Sequence[JoinTask], eps: int) -> List[int]:
         """Per-task match counts via bucketed batched kernel dispatch;
@@ -481,15 +514,18 @@ class PallasJoinExecutor:
 
 
 def make_join_executor(backend: str, join_fn: Callable[..., int],
-                       interpret: bool = True, prune: str = "auto",
-                       artifacts: Optional[JoinArtifactCache] = None):
-    """Build a join executor for ``backend``, degrading pallas -> numpy
-    with a warning when jax is unavailable. ``prune`` selects the pallas
-    grid (``"dense"`` full grid / ``"block"`` block-sparse / ``"bitmap"``
-    block-sparse + cell-exact refinement / ``"auto"`` per-task selection,
-    the default); the numpy executor has no block structure, so it
-    accepts the adaptive default as a no-op but rejects an explicit
-    ``"block"`` or ``"bitmap"`` request it cannot honor."""
+                       prune: str = "auto",
+                       artifacts: Optional[JoinArtifactCache] = None,
+                       devices: Optional[Sequence[Any]] = None):
+    """Build a join executor for ``backend``. ``prune`` selects the
+    pallas grid (``"dense"`` full grid / ``"block"`` block-sparse /
+    ``"bitmap"`` block-sparse + cell-exact refinement / ``"auto"``
+    per-task selection, the default); the numpy executor has no block
+    structure, so it accepts the adaptive default as a no-op but rejects
+    an explicit ``"block"`` or ``"bitmap"`` request it cannot honor.
+    ``devices`` are the devices the pallas executor dispatches to (jax's
+    default devices when ``None``); their platform decides interpret
+    mode."""
     if backend == "numpy":
         if prune in ("block", "bitmap"):
             raise ValueError(
@@ -497,14 +533,7 @@ def make_join_executor(backend: str, join_fn: Callable[..., int],
                 f"numpy executor has no block grid to prune")
         return NumpyJoinExecutor(join_fn)
     if backend == "pallas":
-        try:
-            return PallasJoinExecutor(interpret=interpret, prune=prune,
-                                      artifacts=artifacts)
-        except ImportError as e:                 # jax not available: degrade
-            import warnings
-            warnings.warn(f"join_backend='pallas' unavailable ({e}); "
-                          f"falling back to the numpy executor",
-                          RuntimeWarning, stacklevel=3)
-            return NumpyJoinExecutor(join_fn)
+        return PallasJoinExecutor(prune=prune, artifacts=artifacts,
+                                  devices=devices)
     raise ValueError(f"unknown join backend {backend!r}; "
                      f"known: {JOIN_BACKENDS}")

@@ -18,16 +18,17 @@ that the simulated backend only models:
     ``measured_ship_bytes``).
   * **Join compute** — each node's chunk-pair batch is shape-bucketed
     and dispatched to the ``kernels/simjoin`` Pallas kernel on that
-    node's device, compiled (``interpret=False``) when the platform
-    supports it (TPU/GPU; auto-detected, overridable), interpret-mode
-    on CPU. Per-node kernel wall-clock is measured and combined with
-    the §4.1 ``max_n`` convention into ``measured_compute_s``.
+    node's device: compiled (``interpret=False``) on the chip, interpret
+    mode only when the mesh's devices are CPU devices
+    (``simjoin.interpret_mode``). Per-node kernel wall-clock is measured
+    and combined with the §4.1 ``max_n`` convention into
+    ``measured_compute_s``.
 
-On CPU-only hosts, run under
+With fewer devices than nodes the node axis wraps (node ``k`` lives on
+device ``k % n_devices``): 8 nodes on one chip share its device. On
+CPU-only hosts, run under
 ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` so jax exposes N
-virtual CPU devices and CI exercises real cross-device placement; with
-fewer devices than nodes the node axis wraps (node ``k`` lives on device
-``k % n_devices``).
+virtual CPU devices and CI exercises real cross-device placement.
 
 Modeled ``time_*_s`` fields are still reported (computed from the same
 plans) so the two backends remain directly comparable; the measured
@@ -54,13 +55,6 @@ from repro.backend.simulated import SimulatedBackend
 from repro.faults.errors import RetryExhaustedError
 
 
-def compiled_mode_supported() -> bool:
-    """Whether the default jax platform compiles Pallas kernels
-    (TPU via Mosaic, GPU via Triton); CPU runs interpret-mode only."""
-    import jax
-    return jax.default_backend() in ("tpu", "gpu")
-
-
 class JaxMeshBackend(SimulatedBackend):
     """Execution over a one-axis device mesh: nodes -> jax devices."""
 
@@ -68,38 +62,31 @@ class JaxMeshBackend(SimulatedBackend):
 
     def __init__(self, n_nodes: int, cost_model: Optional[CostModel] = None,
                  devices: Optional[Sequence[Any]] = None,
-                 compiled: Optional[bool] = None,
                  execute_joins: bool = True, prune: str = "auto",
                  mqo: str = "off"):
         import jax
         from jax.sharding import Mesh
-        # The mesh backend always joins through the Pallas kernel; the
-        # simulated parent's executor field holds the dispatch cache and
-        # the prune preprocessing shared with the per-node path here.
-        interpret = not (compiled_mode_supported() if compiled is None
-                         else compiled)
-        super().__init__(n_nodes, cost_model=cost_model,
-                         join_backend="pallas", execute_joins=execute_joins,
-                         interpret=interpret, prune=prune, mqo=mqo)
-        self.interpret = interpret
         self.devices = tuple(devices if devices is not None
                              else jax.devices())
         if not self.devices:
             raise ValueError("jax reports no devices")
+        # The mesh backend always joins through the Pallas kernel on the
+        # mesh's devices (their platform decides interpret mode); the
+        # simulated parent's executor field holds the dispatch cache and
+        # the prune preprocessing shared with the per-node path here.
+        super().__init__(n_nodes, cost_model=cost_model,
+                         join_backend="pallas", execute_joins=execute_joins,
+                         prune=prune, mqo=mqo, devices=self.devices)
         if len(self.devices) < n_nodes:
+            hint = (f" Set XLA_FLAGS=--xla_force_host_platform_device_count"
+                    f"={n_nodes} for one CPU device per node."
+                    if self.devices[0].platform == "cpu" else "")
             warnings.warn(
                 f"jax_mesh: {n_nodes} nodes over {len(self.devices)} "
                 f"devices — the node axis wraps (node k -> device "
-                f"k % {len(self.devices)}). Set XLA_FLAGS="
-                f"--xla_force_host_platform_device_count={n_nodes} for "
-                f"one CPU device per node.", RuntimeWarning, stacklevel=2)
+                f"k % {len(self.devices)}).{hint}", RuntimeWarning,
+                stacklevel=2)
         self.mesh = Mesh(np.array(self.devices), ("node",))
-        # The parent already built a PallasJoinExecutor; per-node dispatch
-        # goes through its iter_batches/dispatch seam below.
-        from repro.backend.executors import PallasJoinExecutor
-        if not isinstance(self.executor, PallasJoinExecutor):
-            raise ImportError(
-                "jax_mesh backend requires the Pallas simjoin kernel")
         # Committed cache buffers, one per replica copy: chunk id ->
         # {holder node -> device array}. ``_buffer_node`` tracks the
         # PRIMARY holder (the CacheState ``primary_map`` view the parity
@@ -598,16 +585,16 @@ def make_backend(backend: str, n_nodes: int,
                  join_fn: Optional[Callable[..., int]] = None,
                  join_backend: str = "numpy", execute_joins: bool = True,
                  devices: Optional[Sequence[Any]] = None,
-                 compiled: Optional[bool] = None,
                  prune: str = "auto", mqo: str = "off") -> SimulatedBackend:
-    """Build an execution backend by name, degrading ``jax_mesh`` ->
-    ``simulated`` with a warning when jax is unavailable. ``prune``
-    selects the Pallas join grid (``"dense"`` / ``"block"``-sparse /
-    ``"bitmap"`` block-sparse + cell-exact hierarchical-bitmap
-    refinement / ``"auto"`` per-task selection on post-bitmap refined
-    pair counts, the default) and applies to any backend that joins
-    through the Pallas kernel; ``mqo`` toggles cross-batch task dedup
-    in ``execute_batch`` (off = seed parity)."""
+    """Build an execution backend by name. ``prune`` selects the Pallas
+    join grid (``"dense"`` / ``"block"``-sparse / ``"bitmap"``
+    block-sparse + cell-exact hierarchical-bitmap refinement /
+    ``"auto"`` per-task selection on post-bitmap refined pair counts,
+    the default) and applies to any backend that joins through the
+    Pallas kernel; ``mqo`` toggles cross-batch task dedup in
+    ``execute_batch`` (off = seed parity). ``devices`` are the mesh's
+    devices for ``jax_mesh`` (all of jax's devices when ``None``); the
+    simulated backend runs on jax's default devices."""
     if backend == "simulated":
         return SimulatedBackend(n_nodes, cost_model=cost_model,
                                 join_fn=join_fn, join_backend=join_backend,
@@ -619,17 +606,7 @@ def make_backend(backend: str, n_nodes: int,
                 "join_fn overrides the numpy executor's predicate; the "
                 "jax_mesh backend always runs the Pallas simjoin kernel "
                 "— pass one or the other")
-        try:
-            return JaxMeshBackend(n_nodes, cost_model=cost_model,
-                                  devices=devices, compiled=compiled,
-                                  execute_joins=execute_joins, prune=prune,
-                                  mqo=mqo)
-        except ImportError as e:
-            warnings.warn(f"backend='jax_mesh' unavailable ({e}); "
-                          f"falling back to the simulated backend",
-                          RuntimeWarning, stacklevel=2)
-            return SimulatedBackend(n_nodes, cost_model=cost_model,
-                                    join_fn=join_fn,
-                                    join_backend=join_backend,
-                                    execute_joins=execute_joins, mqo=mqo)
+        return JaxMeshBackend(n_nodes, cost_model=cost_model,
+                              devices=devices, execute_joins=execute_joins,
+                              prune=prune, mqo=mqo)
     raise ValueError(f"unknown backend {backend!r}; known: {BACKENDS}")

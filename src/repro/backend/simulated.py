@@ -14,8 +14,8 @@ directly comparable.
 """
 from __future__ import annotations
 
-from typing import (TYPE_CHECKING, Callable, Dict, List, Optional, Sequence,
-                    Tuple)
+from typing import (TYPE_CHECKING, Any, Callable, Dict, List, Optional,
+                    Sequence, Tuple)
 
 import numpy as np
 
@@ -47,8 +47,8 @@ class SimulatedBackend:
     def __init__(self, n_nodes: int, cost_model: Optional[CostModel] = None,
                  join_fn: Optional[Callable[..., int]] = None,
                  join_backend: str = "numpy", execute_joins: bool = True,
-                 interpret: bool = True, prune: str = "auto",
-                 mqo: str = "off"):
+                 prune: str = "auto", mqo: str = "off",
+                 devices: Optional[Sequence[Any]] = None):
         if mqo not in MQO_MODES:
             raise ValueError(f"unknown mqo mode {mqo!r}; "
                              f"expected one of {MQO_MODES}")
@@ -57,8 +57,10 @@ class SimulatedBackend:
         self.join_fn = join_fn or count_similar_pairs_np
         self.execute_joins = execute_joins
         self.mqo = mqo
+        # A pallas executor dispatches to ``devices`` (jax's default
+        # devices when None), whose platform decides interpret mode.
         self.executor = make_join_executor(join_backend, self.join_fn,
-                                           interpret=interpret, prune=prune)
+                                           prune=prune, devices=devices)
         # The pallas executor owns a JoinArtifactCache; the backend wires
         # its invalidation into CacheState at bind time (the numpy
         # executor has no host prep to memoize — artifacts stays None).

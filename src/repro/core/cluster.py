@@ -18,7 +18,7 @@ carried out is the backend's job (``repro.backend``):
     device-resident buffers pinned to the nodes of their ``CacheState``
     replica set, ship decisions become measured cross-device transfers, and
     each node's simjoin batch dispatches to the Pallas kernel on that
-    node's device (compiled where the platform supports it).
+    node's device (compiled on the chip, interpret mode on CPU devices).
 
 Join execution backends for the simulated path (``join_backend``):
 
@@ -26,9 +26,9 @@ Join execution backends for the simulated path (``join_backend``):
     per chunk pair (``join_fn`` override preserved).
   * ``"pallas"`` — the batched executor: BLOCK-padded, shape-bucketed
     pair batches dispatched to the ``kernels/simjoin`` Pallas kernel
-    (interpret-mode by default, so it runs on CPU CI and compiles on
-    TPU). Its ``prune`` knob selects the grid per task: ``"dense"``
-    (every block pair evaluated), ``"block"`` (spatially sorted
+    (compiled on the chip; interpret mode on CPU devices only). Its
+    ``prune`` knob selects the grid per task: ``"dense"`` (every block
+    pair evaluated), ``"block"`` (spatially sorted
     coordinates, host-pruned block pairs scalar-prefetched into the
     kernel), ``"bitmap"`` (block-sparse plus a cell-exact second stage
     — hierarchical occupancy bitmaps kill bbox-surviving pairs whose
@@ -83,7 +83,6 @@ class RawArrayCluster:
                  reuse: str = "off",
                  backend: str = "simulated",
                  devices: Optional[Sequence[Any]] = None,
-                 compiled: Optional[bool] = None,
                  prune: str = "auto",
                  mqo: str = "off",
                  result_cache: str = "off",
@@ -107,7 +106,7 @@ class RawArrayCluster:
         self.backend = make_backend(
             backend, n_nodes, cost_model=cost_model, join_fn=join_fn,
             join_backend=join_backend, execute_joins=execute_joins,
-            devices=devices, compiled=compiled, prune=prune, mqo=mqo)
+            devices=devices, prune=prune, mqo=mqo)
         self.coordinator = CacheCoordinator(
             catalog, reader, n_nodes, node_budget_bytes, policy=policy,
             placement_mode=placement_mode, min_cells=min_cells,

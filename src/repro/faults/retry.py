@@ -16,11 +16,16 @@ from __future__ import annotations
 
 import time
 from dataclasses import dataclass
-from typing import Any, Callable, Mapping, Optional, Tuple, Union
+from typing import (TYPE_CHECKING, Any, Callable, Mapping, Optional, Tuple,
+                    Union)
 
-from repro.core.geometry import Box, residual_boxes
 from repro.faults.errors import RetryExhaustedError, TransientFaultError
 from repro.obs.clock import Clock, MONOTONIC, as_clock
+
+if TYPE_CHECKING:
+    # repro.core imports this module; importing it back here would make
+    # ``import repro.backend`` before ``import repro.core`` circular.
+    from repro.core.geometry import Box
 
 
 @dataclass(frozen=True)
@@ -164,6 +169,7 @@ def make_degraded(query_box: Box, failed_boxes: Tuple[Box, ...],
                   matches: int = 0) -> DegradedResult:
     """Build a :class:`DegradedResult`, computing ``served_boxes`` as
     the exact residual of ``query_box`` minus ``failed_boxes``."""
+    from repro.core.geometry import residual_boxes
     served = tuple(residual_boxes(query_box, list(failed_boxes)))
     return DegradedResult(query_box=query_box, served_boxes=served,
                           failed_boxes=tuple(failed_boxes),

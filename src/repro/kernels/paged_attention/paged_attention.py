@@ -101,9 +101,7 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
-        # jax renamed TPUCompilerParams -> CompilerParams; accept both.
-        compiler_params=getattr(pltpu, "CompilerParams",
-                                getattr(pltpu, "TPUCompilerParams", None))(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
     )(page_table, seq_lens, q, k_pages, v_pages)

@@ -1,7 +1,7 @@
 """Host-side block pruning for the block-sparse simjoin kernel.
 
 Pure-numpy preprocessing that turns a coordinate set into the inputs the
-``simjoin_pruned_block_counts`` kernel consumes:
+``simjoin_pruned_count`` kernel consumes:
 
   1. ``spatial_sort`` orders cells along the longest dimension of their
      bounding box (lexicographic tie-break over the remaining

@@ -10,13 +10,9 @@ import jax
 
 
 def auto_axis_kwargs(n_axes: int) -> dict:
-    """``axis_types=(AxisType.Auto,) * n`` where the installed jax has
-    ``jax.sharding.AxisType`` (0.5+); empty kwargs on older releases
-    whose ``make_mesh`` takes no ``axis_types`` (Auto is the default)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
+    """``make_mesh`` keyword arguments giving every one of ``n_axes``
+    axes the ``Auto`` sharding type."""
+    return {"axis_types": (jax.sharding.AxisType.Auto,) * n_axes}
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:

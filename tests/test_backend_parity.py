@@ -131,22 +131,18 @@ def test_measured_fields_by_backend(dataset):
     assert "measured_net_s" not in workload_summary(sim)
 
 
-@pytest.mark.slow
 def test_compiled_mode_parity(dataset):
-    """With ``compiled=True`` (TPU/GPU only) the mesh backend's compiled
-    Pallas dispatch returns the same match counts as the simulated
-    backend; skipped on CPU, where Pallas has no compiled path."""
-    from repro.backend.jax_mesh import compiled_mode_supported
-    if not compiled_mode_supported():
-        pytest.skip("compiled Pallas needs TPU/GPU (CPU is interpret-only)")
+    """The mesh backend's interpret flag follows the platform of the
+    devices it holds — interpret mode on CPU devices only, compiled on
+    the chip — and its Pallas dispatch returns the same match counts as
+    the simulated backend's numpy reference either way."""
     catalog, _ = dataset
     queries = fixed_workload(catalog)
-    catalog_, data = dataset
     sim = make(dataset, "simulated").run_workload(queries)
-    mesh = RawArrayCluster(catalog_, FileReader(catalog_, data), N_NODES,
-                           NODE_BUDGET, policy="cost", min_cells=64,
-                           backend="jax_mesh",
-                           compiled=True).run_workload(queries)
+    cluster = make(dataset, "jax_mesh")
+    mesh = cluster.run_workload(queries)
+    platform = cluster.backend.devices[0].platform
+    assert cluster.executor.interpret == (platform == "cpu")
     assert [e.matches for e in mesh] == [e.matches for e in sim]
 
 
@@ -156,3 +152,4 @@ def test_backend_factory_validation():
     with pytest.raises(ValueError, match="Pallas simjoin kernel"):
         make_backend("jax_mesh", 4, join_fn=lambda a, b, e, s: 0)
     assert isinstance(make_backend("simulated", 4), SimulatedBackend)
+    assert isinstance(make_backend("jax_mesh", 4), JaxMeshBackend)
