@@ -17,7 +17,8 @@ over the same cells.
 Without a TPU it exits non-zero before any phase runs. The last line of
 standard output is ``{"ok": true, "device": {...}}``; earlier lines
 report the scale, the interpret flag, the compile count, wall times,
-``measured_compute_s`` and the mesh backend's ``device_stats``. Wall
+``dispatch_s``, the kernel launches and the mesh backend's
+``device_stats``. Wall
 times include compilation on a cold run; they are not benchmark
 numbers.
 """
@@ -146,7 +147,7 @@ def run_phase(name: str, files, fmt: str, queries_for, eps: int, devices,
           f"{compiles} compiles ({dict(ops.TRACE_COUNTS)}), "
           f"prep_s={sum_of(cold, 'prep_s'):.3f}, "
           f"dispatch_s={sum_of(cold, 'dispatch_s'):.3f}, "
-          f"measured_compute_s={sum_of(cold, 'measured_compute_s'):.6f}, "
+          f"dispatch_launches={sum_of(cold, 'dispatch_launches')}, "
           f"measured_ship_bytes={sum_of(cold, 'measured_ship_bytes')}, "
           f"matches={[e.matches for e in cold]}")
     if warm:
@@ -159,8 +160,7 @@ def run_phase(name: str, files, fmt: str, queries_for, eps: int, devices,
         print(f"{name}: warm repeat in {warm_s:.3f} s, {new} new compiles, "
               f"prep_s={sum_of(again, 'prep_s'):.3f}, "
               f"dispatch_s={sum_of(again, 'dispatch_s'):.3f}, "
-              f"measured_compute_s="
-              f"{sum_of(again, 'measured_compute_s'):.6f}")
+              f"dispatch_launches={sum_of(again, 'dispatch_launches')}")
         if new:
             fail(f"{name}: the warm repeat compiled {new} kernels")
         if [e.matches for e in again] != [e.matches for e in cold]:

@@ -17,6 +17,7 @@ from repro.arrayio.generator import GeneratedFile
 from repro.core.chunk import FileMeta
 from repro.core.geometry import Box, enclosing
 from repro.faults.errors import ScanError
+from repro.obs.trace import NULL_TRACER
 
 
 @dataclasses.dataclass
@@ -74,12 +75,19 @@ def build_catalog(generated: Sequence[GeneratedFile],
 
 class FileReader:
     """Read cells of a raw file — from memory (cost-modeled) or from disk
-    through the real format decoder."""
+    through the real format decoder.
+
+    ``bytes_read`` counts the raw bytes actually read and decoded from
+    disk (a memoized or in-memory file reads none); each such read is
+    traced as a ``scan.read`` span on ``tracer``, which the coordinator
+    sets to its telemetry's tracer."""
 
     def __init__(self, catalog: Catalog,
                  data: Optional[Dict[int, Tuple[np.ndarray, np.ndarray]]] = None):
         self.catalog = catalog
         self._data = data or {}
+        self.bytes_read = 0
+        self.tracer = NULL_TRACER
 
     def read(self, file_id: int) -> Tuple[np.ndarray, np.ndarray]:
         """Cells of ``file_id`` as ``(coords, attrs)``, memoized.
@@ -92,11 +100,13 @@ class FileReader:
         if file_id in self._data:
             return self._data[file_id]
         meta = self.catalog.by_id(file_id)
-        try:
-            coords, attrs = formats.read_array_file(meta.path, meta.fmt)
-        except ScanError:
-            raise
-        except (OSError, ValueError, EOFError, IndexError, KeyError) as e:
-            raise ScanError(file_id, meta.path, cause=e) from e
+        with self.tracer.span("scan.read", file=file_id):
+            try:
+                coords, attrs = formats.read_array_file(meta.path, meta.fmt)
+            except ScanError:
+                raise
+            except (OSError, ValueError, EOFError, IndexError, KeyError) as e:
+                raise ScanError(file_id, meta.path, cause=e) from e
+        self.bytes_read += meta.file_bytes
         self._data[file_id] = (coords, attrs)
         return coords, attrs

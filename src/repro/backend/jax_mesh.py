@@ -20,9 +20,11 @@ that the simulated backend only models:
     and dispatched to the ``kernels/simjoin`` Pallas kernel on that
     node's device: compiled (``interpret=False``) on the chip, interpret
     mode only when the mesh's devices are CPU devices
-    (``simjoin.interpret_mode``). Per-node kernel wall-clock is measured
-    and combined with the §4.1 ``max_n`` convention into
-    ``measured_compute_s``.
+    (``simjoin.interpret_mode``). The whole dispatch is timed as
+    ``dispatch_s``; with telemetry on, each batch's steps are traced as
+    ``dispatch.lookup`` / ``dispatch.put`` / ``dispatch.launch`` /
+    ``dispatch.wait`` / ``dispatch.readback`` spans, and every query counts its launches and
+    placed bytes (``dispatch_launches`` / ``dispatch_put_bytes``).
 
 With fewer devices than nodes the node axis wraps (node ``k`` lives on
 device ``k % n_devices``): 8 nodes on one chip share its device. On
@@ -168,6 +170,7 @@ class JaxMeshBackend(SimulatedBackend):
         hooks on its ``CacheState`` so buffers track residency."""
         super().bind(coordinator)
         coordinator.cache.add_listener(self)
+        self._io_seen = self._io_totals()
 
     # ------------------------- DeviceBindingListener (cache life-cycle) --
 
@@ -425,80 +428,84 @@ class JaxMeshBackend(SimulatedBackend):
             keys.append((ka, kb))
         return (dev, batch.fn_key, int(eps), tuple(keys))
 
-    def _dispatch_joins(self, tasks, eps: int
-                        ) -> Tuple[List[int], float, Dict[str, int]]:
+    def _count_tasks(self, tasks, eps: int
+                     ) -> Tuple[List[int], Dict[str, int]]:
         """Shape-bucketed per-node Pallas dispatch: every bucket's stacked
         batch (dense or block-sparse per the executor's ``prune`` knob)
         is placed on its node's device before the kernel call — ONCE per
         resident chunk set: device-placed stacks are pinned per
         (device, batch content) and re-dispatched directly on repeat
-        queries, invalidated with their chunks' residency. Returns
-        (per-task match counts, measured compute seconds = max over
-        nodes — the §4.1 ``max_n`` convention applied to measured
-        per-node wall-clock — and the query's counters)."""
-        import contextlib
-
+        queries, invalidated with their chunks' residency. Returns the
+        per-task match counts and the query's counters: the executor's
+        prep stats plus ``dispatch_s`` (wall-clock of the loop below),
+        ``dispatch_launches`` and ``dispatch_put_bytes``."""
         import jax
         import jax.numpy as jnp
-        node_time: Dict[int, float] = {}
         counts = [0] * len(tasks)
         batches, stats = self.executor.iter_batches(tasks, eps,
                                                     by_node=True)
-        telemetry_on = self.telemetry.enabled
-        dispatch_span = self.telemetry.tracer.begin("dispatch",
-                                                    batches=len(batches))
+        tracer = self.telemetry.tracer
+        dispatch_span = tracer.begin("dispatch", batches=len(batches))
+        put_bytes = 0
         t0_all = time.perf_counter()
         for batch in batches:
-            dev = self.device_for_node(batch.node)
-            ckey = self._pinned_key(batch, tasks, eps, dev)
-            arrays = self._pinned.pop(ckey, None) if ckey is not None \
-                else None
-            if arrays is not None:
-                self.device_stats["pinned_batch_hits"] += 1
-                self._pinned[ckey] = arrays      # LRU refresh (reinsert)
-                self._enforce_pinned_cap()
-            else:
-                arrays = tuple(jax.device_put(jnp.asarray(x), dev)
-                               for x in batch.arrays)
-                for x in arrays:
-                    x.block_until_ready()
-                if ckey is not None:
-                    self.device_stats["pinned_batch_misses"] += 1
-                    self._pinned[ckey] = arrays
-                    for ka, kb in ckey[3]:
-                        self._pinned_by_chunk.setdefault(
-                            ka[0], set()).add(ckey)
-                        self._pinned_by_chunk.setdefault(
-                            kb[0], set()).add(ckey)
+            with tracer.span("dispatch.lookup"):
+                dev = self.device_for_node(batch.node)
+                ckey = self._pinned_key(batch, tasks, eps, dev)
+                arrays = (self._pinned.pop(ckey, None) if ckey is not None
+                          else None)
+                if arrays is not None:
+                    self.device_stats["pinned_batch_hits"] += 1
+                    self._pinned[ckey] = arrays  # LRU refresh (reinsert)
                     self._enforce_pinned_cap()
-            # jax.profiler annotation: names this kernel launch in any
-            # captured XLA/Perfetto device profile (telemetry-on only —
-            # the off path stays annotation-free).
-            annot = (jax.profiler.TraceAnnotation(
-                f"simjoin.node{batch.node}") if telemetry_on
-                else contextlib.nullcontext())
-            t0 = time.perf_counter()
-            with annot:
+            if arrays is None:
+                with tracer.span("dispatch.put"):
+                    arrays = tuple(jax.device_put(jnp.asarray(x), dev)
+                                   for x in batch.arrays)
+                    for x in arrays:
+                        x.block_until_ready()
+                    put_bytes += sum(x.nbytes for x in arrays)
+                    if ckey is not None:
+                        self.device_stats["pinned_batch_misses"] += 1
+                        self._pinned[ckey] = arrays
+                        for ka, kb in ckey[3]:
+                            self._pinned_by_chunk.setdefault(
+                                ka[0], set()).add(ckey)
+                            self._pinned_by_chunk.setdefault(
+                                kb[0], set()).add(ckey)
+                        self._enforce_pinned_cap()
+            with tracer.span("dispatch.launch"):
                 got = self.executor.dispatch(batch, eps, arrays=arrays)
+            with tracer.span("dispatch.wait"):
                 got.block_until_ready()
-            node_time[batch.node] = (node_time.get(batch.node, 0.0)
-                                     + time.perf_counter() - t0)
-            for i, c in zip(batch.idxs, np.asarray(got)):
-                counts[i] = int(c)
+            with tracer.span("dispatch.readback"):
+                for i, c in zip(batch.idxs, np.asarray(got)):
+                    counts[i] = int(c)
         stats["dispatch_s"] = time.perf_counter() - t0_all
-        self.telemetry.tracer.end(dispatch_span)
-        if telemetry_on:
+        stats["dispatch_launches"] = len(batches)
+        stats["dispatch_put_bytes"] = put_bytes
+        tracer.end(dispatch_span)
+        if self.telemetry.enabled:
             self._mirror_device_stats()
-        return counts, max(node_time.values(), default=0.0), stats
+        return counts, stats
 
-    def _count_tasks(self, tasks, eps: int
-                     ) -> Tuple[List[int], Dict[str, float]]:
-        """Batch-execution seam: per-task counts via the per-node pinned
-        dispatch path, with the measured kernel wall-clock (max over
-        nodes) folded into the stats under ``measured_compute_s``."""
-        counts, node_max_s, stats = self._dispatch_joins(tasks, eps)
-        stats["measured_compute_s"] = node_max_s
-        return counts, dict(stats)
+    def _io_totals(self) -> Tuple[float, int]:
+        """Cumulative (bytes the device reconcile materialized, moved or
+        copied; raw bytes the coordinator's reader read from disk)."""
+        d = self.device_stats
+        synced = (d["committed_bytes_materialized"]
+                  + d["committed_bytes_moved"] + d["replica_bytes_copied"])
+        return synced, getattr(self.coordinator.reader, "bytes_read", 0)
+
+    def _io_fields(self) -> Dict[str, int]:
+        """``device_sync_bytes`` / ``scan_read_bytes`` of the query being
+        built: the growth of :meth:`_io_totals` since the previous one
+        (a batch's policy round and scans land on its first query)."""
+        now = self._io_totals()
+        synced, read = (a - b for a, b in zip(now, self._io_seen))
+        self._io_seen = now
+        return {"device_sync_bytes": int(synced),
+                "scan_read_bytes": int(read)}
 
     def _measured_ship(self, query: "SimilarityJoinQuery",
                        report: "QueryReport",
@@ -534,8 +541,9 @@ class JaxMeshBackend(SimulatedBackend):
         time_scan = self.modeled_scan_time(report)
         time_net = self.modeled_net_time(report)
         drop, ship_ops = self._guard_transfers(query, report)
-        tasks, work_by_node, coords_cache, _ = self.gather_join_tasks(
-            query, report, exclude=drop)
+        with self.telemetry.tracer.span("gather"):
+            tasks, work_by_node, coords_cache, _ = self.gather_join_tasks(
+                query, report, exclude=drop)
         # Ship what the plan ships: the sliced extent under semantic
         # reuse, the whole chunk otherwise (a shipped chunk becomes a
         # full replica the placement round may keep). The host-level
@@ -544,14 +552,12 @@ class JaxMeshBackend(SimulatedBackend):
         measured_net, measured_bytes = self._measured_ship(
             query, report, coords_cache, skip=drop)
         matches: Optional[int] = None
-        measured_compute = 0.0
         stats: Dict[str, int] = {}
         join_ops: List[str] = []
         if report.join_plan is not None and self.execute_joins:
             try:
                 counts, stats = self._guarded_count(tasks, query.eps)
                 matches = sum(counts)
-                measured_compute = stats.get("measured_compute_s", 0.0)
             except RetryExhaustedError as e:
                 join_ops.append(e.op)
                 matches = 0
@@ -566,7 +572,6 @@ class JaxMeshBackend(SimulatedBackend):
             time_compute_s=time_compute, time_opt_s=t_opt, matches=matches,
             backend=self.name,
             measured_net_s=measured_net,
-            measured_compute_s=measured_compute,
             measured_ship_bytes=measured_bytes,
             block_pairs_total=stats.get("block_pairs_total"),
             block_pairs_evaluated=stats.get("block_pairs_evaluated"),
@@ -576,6 +581,9 @@ class JaxMeshBackend(SimulatedBackend):
             artifact_misses=stats.get("artifact_misses"),
             block_pairs_bitmap_killed=stats.get("block_pairs_bitmap_killed"),
             bitmap_build_s=stats.get("bitmap_build_s"),
+            dispatch_launches=stats.get("dispatch_launches", 0),
+            dispatch_put_bytes=stats.get("dispatch_put_bytes", 0),
+            **self._io_fields(),
             **self._resilience_fields(report),
             **self._fault_fields(degraded)))
 

@@ -445,8 +445,14 @@ class SimulatedBackend:
             report=report, time_scan_s=0.0, time_net_s=0.0,
             time_compute_s=0.0, time_opt_s=0.0,
             matches=report.cached_matches, backend=self.name,
+            **self._io_fields(),
             **self._resilience_fields(report),
             **self._fault_fields(None)))
+
+    def _io_fields(self) -> Dict[str, int]:
+        """Per-query device-sync and raw-read byte counters: none here
+        (the mesh backend overrides this)."""
+        return {}
 
     def _measured_ship(self, query: "SimilarityJoinQuery",
                        report: "QueryReport",
@@ -585,7 +591,7 @@ class SimulatedBackend:
         *unique* tasks, not query count. Per-query *modeled* phase times
         are unchanged (they describe the plan, keeping MQO-on/off rows
         comparable); the batch-level executor stats (block-pair
-        counters, prep/dispatch wall-clock, measured compute) are
+        counters, prep/dispatch wall-clock, dispatch launches) are
         attributed to the batch's last planned query, mirroring how the
         coordinator attributes its per-batch policy-round time."""
         queries = list(queries)
@@ -632,10 +638,6 @@ class SimulatedBackend:
             degraded = self._assemble_degraded(q, r, drop, ship_ops,
                                                join_ops, matches)
             stats = batch_stats if i == last_live else {}
-            measuring = m_net is not None
-            m_compute = (stats.get("measured_compute_s",
-                                   0.0 if measuring else None)
-                         if measuring else None)
             t_opt = r.opt_time_chunking_s + r.opt_time_evict_place_s
             total, executed, shared = counters[i]
             out.append(self._record(ExecutedQuery(
@@ -644,8 +646,7 @@ class SimulatedBackend:
                 time_compute_s=(max(work_by_node.values(), default=0)
                                 / self.cost.cell_pairs_per_sec),
                 time_opt_s=t_opt, matches=matches, backend=self.name,
-                measured_net_s=m_net, measured_compute_s=m_compute,
-                measured_ship_bytes=m_bytes,
+                measured_net_s=m_net, measured_ship_bytes=m_bytes,
                 block_pairs_total=stats.get("block_pairs_total"),
                 block_pairs_evaluated=stats.get("block_pairs_evaluated"),
                 prep_s=stats.get("prep_s"),
@@ -655,8 +656,11 @@ class SimulatedBackend:
                 block_pairs_bitmap_killed=stats.get(
                     "block_pairs_bitmap_killed"),
                 bitmap_build_s=stats.get("bitmap_build_s"),
+                dispatch_launches=stats.get("dispatch_launches"),
+                dispatch_put_bytes=stats.get("dispatch_put_bytes"),
                 mqo_tasks_total=total, mqo_tasks_executed=executed,
                 mqo_shared_hits=shared,
+                **self._io_fields(),
                 **self._resilience_fields(r),
                 **self._fault_fields(degraded))))
         return out

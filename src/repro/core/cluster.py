@@ -67,6 +67,10 @@ __all__ = ["BACKENDS", "CostModel", "ExecutedQuery", "JOIN_BACKENDS",
            "RawArrayCluster", "count_similar_pairs_np", "make_backend",
            "make_join_executor", "workload_summary"]
 
+#: ``ExecutedQuery`` counters copied onto each ``query`` span's args.
+QUERY_COUNTERS = ("dispatch_launches", "dispatch_put_bytes",
+                  "device_sync_bytes", "scan_read_bytes")
+
 
 class RawArrayCluster:
     """N worker nodes + coordinator, wired to the caching stack and an
@@ -118,6 +122,11 @@ class RawArrayCluster:
             replication_threshold=replication_threshold,
             telemetry=telemetry, faults=faults, retry=retry, audit=audit)
         self.backend.bind(self.coordinator)
+        if self.telemetry.enabled:
+            # Mirror every span into a captured jax profiler trace as a
+            # host annotation of the same name (one clock with the device).
+            from jax.profiler import TraceAnnotation
+            self.telemetry.tracer.mirror = TraceAnnotation
 
     @property
     def telemetry(self) -> Telemetry:
@@ -131,7 +140,7 @@ class RawArrayCluster:
         ``path`` (Perfetto/``chrome://tracing``-loadable); returns
         ``path``. An off-mode cluster writes an empty—but well-formed—
         trace."""
-        return self.telemetry.export_trace(path)
+        return self.telemetry.tracer.export(path)
 
     def summary(self, executed: Sequence[ExecutedQuery]):
         """``workload_summary`` over ``executed``, also surfacing any
@@ -177,11 +186,21 @@ class RawArrayCluster:
         """Admit one query through the coordinator and execute its plan
         (a result-cache hit report short-circuits execution; a planned
         query's computed match count is written back to the tier).
-        Traced as one ``query`` span when telemetry is on."""
-        with self.telemetry.tracer.span("query", cat="query"):
+        Traced as one ``query`` span when telemetry is on, whose args are
+        the coordinator's query index and the query's device-path
+        counters (:data:`QUERY_COUNTERS`), so every span of one request
+        can be grouped."""
+        with self.telemetry.tracer.span(
+                "query", cat="query",
+                index=self.coordinator.query_counter + 1) as span:
             report = self.coordinator.process_query(query)
             executed = self.backend.execute(query, report)
             self.coordinator.record_result(query, executed)
+            if span is not None:
+                for key in QUERY_COUNTERS:
+                    value = getattr(executed, key)
+                    if value is not None:
+                        span.args[key] = value
         return executed
 
     def run_workload(self, queries: Sequence[SimilarityJoinQuery],

@@ -210,6 +210,8 @@ class CacheCoordinator:
         self.telemetry = make_telemetry(telemetry)
         self.clock = (as_clock(clock) if clock is not None
                       else self.telemetry.clock)
+        # Raw reads are traced as ``scan.read`` spans inside the reader.
+        reader.tracer = self.telemetry.tracer
         # Transient-fault pipeline (see ``repro.faults``): the seeded
         # injector behind the ``fault_point`` seam (None = seam never
         # consulted, seed-exact), the shared retrier both the planner
@@ -253,7 +255,7 @@ class CacheCoordinator:
         # :meth:`drain_exec_counters`); ``workload_summary`` surfaces
         # anything still pending after the last query, so post-workload
         # events are never silently lost.
-        self.events = EventChannel(self.telemetry.registry)
+        self.events = EventChannel()
         self.join_history: List[JoinRecord] = []   # Alg. 3 workload W
         self.query_counter = 0
         # Queries that went through the planning pipeline (a result-cache
@@ -459,7 +461,8 @@ class CacheCoordinator:
 
             # Policy rounds reassign the resident set wholesale; reconcile any
             # device-backed buffer bindings (no-op without a device backend).
-            self.cache.sync_devices()
+            with tracer.span("device.sync"):
+                self.cache.sync_devices()
 
             if self.reuse == "on":
                 # Policy rounds reassign the resident set wholesale; reconcile
@@ -696,7 +699,8 @@ class CacheCoordinator:
                     # residency without a fresh scan.
                     self.eviction.discard(cid)
         self.cache.sync_coverage(self.chunks.meta_of)
-        self.cache.sync_devices()
+        with self.telemetry.tracer.span("device.sync"):
+            self.cache.sync_devices()
         event = {
             "failover_readmits": float(readmits),
             "recovery_bytes_from_replica": float(from_replica),
@@ -839,15 +843,17 @@ class CacheCoordinator:
         # Locations at query start: the cached replica set (a one-tuple
         # in the single-copy default), else the home node (the scan just
         # materialized the chunk there).
-        locations = {cm.chunk_id: (self.cache.replicas_of(cm.chunk_id)
-                                   or self.catalog.by_id(cm.file_id).node)
-                     for cm in queried}
-        jplan = plan_join(queried, locations,
-                          0 if query.eps <= 0 else query.eps, self.n_nodes,
-                          ship_bytes=ship_bytes or None)
-        self.join_history.append(JoinRecord(l, tuple(jplan.pairs)))
-        if len(self.join_history) > self.history_window:
-            self.join_history = self.join_history[-self.history_window:]
+        with tracer.span("plan.join", query=l, chunks=len(queried)):
+            locations = {cm.chunk_id: (self.cache.replicas_of(cm.chunk_id)
+                                       or self.catalog.by_id(cm.file_id).node)
+                         for cm in queried}
+            jplan = plan_join(queried, locations,
+                              0 if query.eps <= 0 else query.eps,
+                              self.n_nodes, ship_bytes=ship_bytes or None)
+            self.join_history.append(JoinRecord(l, tuple(jplan.pairs)))
+            if len(self.join_history) > self.history_window:
+                self.join_history = self.join_history[
+                    -self.history_window:]
 
         return _QueryPlan(
             query=query, query_index=l, files_considered=len(candidates),
@@ -920,10 +926,11 @@ class CacheCoordinator:
                     reuse_hits += 1
                     reuse_bytes += sliced
         tracer.end(scan_span)
-        locations = {cm.chunk_id: self.catalog.by_id(cm.file_id).node
-                     for cm in queried}
-        jplan = plan_join(queried, locations, query.eps, self.n_nodes,
-                          ship_bytes=ship_bytes or None)
+        with tracer.span("plan.join", query=l, chunks=len(queried)):
+            locations = {cm.chunk_id: self.catalog.by_id(cm.file_id).node
+                         for cm in queried}
+            jplan = plan_join(queried, locations, query.eps, self.n_nodes,
+                              ship_bytes=ship_bytes or None)
         return _QueryPlan(
             query=query, query_index=l, files_considered=len(candidates),
             files_pruned=0, files_scanned=scans,

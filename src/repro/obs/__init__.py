@@ -6,7 +6,7 @@ Import surface::
                            Span, Tracer, NULL_TRACER,
                            MetricsRegistry, NULL_REGISTRY,
                            Telemetry, EventChannel, NULL_TELEMETRY,
-                           make_telemetry)
+                           make_telemetry, last_recording)
 
 ``repro.obs`` deliberately imports nothing from the rest of the repo,
 so core and backend modules can depend on it without cycles.
@@ -17,7 +17,7 @@ from repro.obs.metrics import (Counter, Gauge, Histogram, MetricsRegistry,
                                NULL_REGISTRY, DEFAULT_BUCKETS)
 from repro.obs.trace import Span, Tracer, NullTracer, NULL_TRACER
 from repro.obs.telemetry import (Telemetry, EventChannel, NULL_TELEMETRY,
-                                 make_telemetry)
+                                 make_telemetry, last_recording)
 
 __all__ = [
     "Clock", "ManualClock", "MonotonicClock", "MONOTONIC", "as_clock",
@@ -25,4 +25,5 @@ __all__ = [
     "DEFAULT_BUCKETS",
     "Span", "Tracer", "NullTracer", "NULL_TRACER",
     "Telemetry", "EventChannel", "NULL_TELEMETRY", "make_telemetry",
+    "last_recording",
 ]

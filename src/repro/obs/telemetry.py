@@ -13,11 +13,13 @@ nothing else.
 ad-hoc ``_pending_exec`` dict (PR 7's replication/failover drain
 channel): policy rounds *post* counter deltas keyed by summary-counter
 name, and the next executed query *drains* them into its
-``ExecutedQuery`` fields.  The channel also mirrors every post into
-``events.*`` registry counters (an unmarked emission group, so the
-mirror never leaks into ``as_summary``), and ``workload_summary``
-surfaces anything still pending after the last query — the ISSUE 8
-satellite fix for events that previously vanished.
+``ExecutedQuery`` fields, and ``workload_summary`` surfaces anything
+still pending after the last query, so no event vanishes.
+
+:func:`last_recording` hands the most recent recording bundle to a
+reader that holds no handle to the cluster that made it — a
+benchmark's metric readers, which see a run's records after the
+cluster is gone.
 """
 from __future__ import annotations
 
@@ -27,7 +29,12 @@ from repro.obs.clock import Clock, MONOTONIC, as_clock
 from repro.obs.metrics import MetricsRegistry, NULL_REGISTRY
 from repro.obs.trace import NullTracer, Tracer, NULL_TRACER
 
-__all__ = ["Telemetry", "EventChannel", "NULL_TELEMETRY", "make_telemetry"]
+__all__ = ["Telemetry", "EventChannel", "NULL_TELEMETRY", "make_telemetry",
+           "last_recording"]
+
+#: The most recently built recording (``mode="on"``) bundle; see
+#: :func:`last_recording`.
+_last_recording: Optional["Telemetry"] = None
 
 
 class Telemetry:
@@ -43,12 +50,14 @@ class Telemetry:
         if mode not in ("on", "off"):
             raise ValueError(f"telemetry mode must be 'on' or 'off', "
                              f"got {mode!r}")
+        global _last_recording
         self.mode = mode
         self.clock = as_clock(clock)
         if mode == "on":
             self.tracer: Union[Tracer, NullTracer] = Tracer(
                 clock=self.clock, pid=pid)
             self.registry: MetricsRegistry = MetricsRegistry()
+            _last_recording = self
         else:
             self.tracer = NULL_TRACER
             self.registry = NULL_REGISTRY
@@ -57,14 +66,6 @@ class Telemetry:
     def enabled(self) -> bool:
         """Whether spans/metrics are actually recorded (``mode == "on"``)."""
         return self.mode == "on"
-
-    def export_trace(self, path: str) -> str:
-        """Write the tracer's Chrome trace JSON to ``path`` (see
-        :meth:`repro.obs.trace.Tracer.export`); returns ``path``."""
-        import json
-        with open(path, "w") as fh:
-            json.dump(self.tracer.to_chrome_trace(), fh)
-        return path
 
 
 class _OffTelemetry(Telemetry):
@@ -93,6 +94,13 @@ def make_telemetry(
                      f"instance, got {spec!r}")
 
 
+def last_recording() -> Optional[Telemetry]:
+    """The most recently built recording bundle in this process (``None``
+    before any). It outlives the cluster that used it, so its spans can
+    be read after a run by code that never held the cluster."""
+    return _last_recording
+
+
 class EventChannel:
     """Pending counter deltas between policy rounds and executed queries.
 
@@ -100,20 +108,15 @@ class EventChannel:
     queries, but their counters belong on ``ExecutedQuery`` records.
     The channel buffers them: :meth:`post` accumulates a delta under a
     summary-counter name, :meth:`drain` hands the buffered dict to the
-    next executed query and empties the channel.  Every post is also
-    mirrored into the registry as an ``events.<key>`` counter (group
-    ``"events"`` — intentionally never marked, so mirrors stay out of
-    ``as_summary`` and exist purely for live inspection)."""
+    next executed query and empties the channel."""
 
-    def __init__(self, registry: Optional[MetricsRegistry] = None):
+    def __init__(self) -> None:
         self._pending: Dict[str, float] = {}
-        self._registry = registry if registry is not None else NULL_REGISTRY
 
     def post(self, key: str, value: float = 1) -> None:
-        """Buffer ``value`` under ``key`` (accumulating with any pending
-        delta for the same key) and mirror it to ``events.<key>``."""
+        """Buffer ``value`` under ``key``, accumulating with any pending
+        delta for the same key."""
         self._pending[key] = self._pending.get(key, 0) + value
-        self._registry.counter(f"events.{key}", group="events").inc(value)
 
     def drain(self) -> Dict[str, float]:
         """All pending deltas, emptying the channel."""

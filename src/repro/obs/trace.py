@@ -6,9 +6,18 @@ The span taxonomy instrumented across the repo (ARCHITECTURE.md
 "Telemetry"):
 
   ``workload`` > ``batch`` / ``query`` > ``query.rewrite``,
-  ``plan.scan``, ``policy.evict``, ``policy.place``,
-  ``policy.replicate``, ``ship``, ``prep``, ``dispatch`` — plus
-  ``recover`` around a simulated node-failure round.
+  ``plan.scan`` (> ``scan.read``), ``plan.join``, ``policy.evict``,
+  ``policy.place``, ``policy.replicate``, ``device.sync``, ``gather``,
+  ``ship``, ``prep``, ``dispatch`` > ``dispatch.lookup``,
+  ``dispatch.put``, ``dispatch.launch``, ``dispatch.wait``,
+  ``dispatch.readback`` — plus ``recover`` around a simulated
+  node-failure round.
+
+A tracer may be handed a ``mirror``: a callable taking a span name and
+returning a context manager, entered when a span begins and exited when
+it ends. The cluster hands it ``jax.profiler.TraceAnnotation``, so every
+span also appears under the same name in a captured profiler trace, on
+the profiler's own clock (this module imports nothing of jax).
 
 Parenting is explicit: every span records its parent's id (the
 innermost open span on the same logical thread at begin time, or an
@@ -28,7 +37,7 @@ from __future__ import annotations
 
 import dataclasses
 import json
-from typing import Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional
 
 from repro.obs.clock import Clock, MONOTONIC
 
@@ -80,15 +89,18 @@ class Tracer:
 
     Single-threaded by design (the repo's pipelines are synchronous):
     one open-span stack provides the implicit parent; ``parent=``
-    overrides it for explicitly re-parented spans."""
+    overrides it for explicitly re-parented spans. ``mirror`` (see the
+    module docstring) is entered and exited with every span."""
 
     enabled = True
 
     def __init__(self, clock: Optional[Clock] = None, pid: int = 0):
         self.clock = clock if clock is not None else MONOTONIC
         self.pid = pid
+        self.mirror: Optional[Callable[[str], Any]] = None
         self.spans: List[Span] = []       # every begun span, begin order
         self._stack: List[Span] = []      # open spans, innermost last
+        self._mirrored: Dict[int, Any] = {}   # span id -> entered mirror
         self._next_id = 1
 
     # ------------------------------------------------------------ spans
@@ -99,6 +111,10 @@ class Tracer:
         innermost currently-open span (``None`` at top level)."""
         pid = parent.span_id if parent is not None else (
             self._stack[-1].span_id if self._stack else None)
+        if self.mirror is not None:
+            ctx = self.mirror(name)
+            ctx.__enter__()
+            self._mirrored[self._next_id] = ctx
         span = Span(span_id=self._next_id, name=name,
                     start=self.clock.now(), cat=cat, tid=tid,
                     parent_id=pid, args=dict(args) if args else None)
@@ -107,17 +123,24 @@ class Tracer:
         self._stack.append(span)
         return span
 
+    def _close(self, span: Span) -> None:
+        """Stamp a span's end, then exit its mirror (if any)."""
+        span.end = self.clock.now()
+        ctx = self._mirrored.pop(span.span_id, None)
+        if ctx is not None:
+            ctx.__exit__(None, None, None)
+
     def end(self, span: Span) -> None:
         """Close a span (and any still-open descendants of it — spans
         close innermost-first, so a leaked child cannot outlive its
         parent in the record)."""
         while self._stack:
             top = self._stack.pop()
-            top.end = self.clock.now()
+            self._close(top)
             if top is span:
                 return
         if span.end is None:              # closed out of stack order
-            span.end = self.clock.now()
+            self._close(span)
 
     def span(self, name: str, cat: str = "phase", tid: int = 0,
              parent: Optional[Span] = None, **args: object) -> _SpanContext:
@@ -201,6 +224,8 @@ class NullTracer:
     def to_chrome_trace(self) -> Dict[str, object]:
         """An empty (but well-formed) trace object."""
         return {"traceEvents": [], "displayTimeUnit": "ms"}
+
+    export = Tracer.export
 
 
 #: Shared telemetry-off tracer (stateless — safe to share globally).

@@ -124,7 +124,10 @@ def test_measured_fields_by_backend(dataset):
     assert all(e.backend == "simulated" for e in sim)
     assert all(e.measured_net_s is not None and e.measured_net_s >= 0
                for e in mesh)
-    assert all(e.measured_compute_s is not None for e in mesh)
+    assert all(e.dispatch_s is not None and e.dispatch_launches is not None
+               for e in mesh)
+    assert all(e.dispatch_launches is None and e.device_sync_bytes is None
+               for e in sim)
     assert all(e.backend == "jax_mesh" for e in mesh)
     summ = workload_summary(mesh)
     assert "measured_net_s" in summ and "measured_ship_bytes" in summ

@@ -98,8 +98,7 @@ def test_replication_off_is_single_copy_seed_path(ptf, backend):
         return {k: v for k, v in s.items()
                 if k not in ("total_time_s", "opt_time_s", "prep_s",
                              "dispatch_s", "bitmap_build_s",
-                             "measured_net_s", "measured_compute_s",
-                             "recovery_s")}
+                             "measured_net_s", "recovery_s")}
     assert modeled(ed) == modeled(ee)
     summary = workload_summary(ee)
     assert "replica_hits" not in summary
