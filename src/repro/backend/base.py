@@ -71,13 +71,15 @@ class ExecutedQuery:
     artifact_misses: Optional[int] = None
     # Device-path counters of the mesh backend (None under the simulated
     # backend; not summed into ``workload_summary``): jitted kernel calls
-    # made and bytes placed on the device for pinned-batch misses by this
+    # made, bytes placed on the device for pinned-batch misses and times
+    # the host blocked on the device (1 with launches, else 0) by this
     # query's dispatch, bytes the post-round device reconcile
     # materialized, moved or copied since the previous query, and raw
     # bytes read from disk (first touch of a file; memoized reads and
     # in-memory catalogs read none) since the previous query.
     dispatch_launches: Optional[int] = None
     dispatch_put_bytes: Optional[int] = None
+    dispatch_host_syncs: Optional[int] = None
     device_sync_bytes: Optional[int] = None
     scan_read_bytes: Optional[int] = None
     # Cell-exact bitmap-prune counters (None unless the bitmap stage ran

@@ -20,11 +20,15 @@ that the simulated backend only models:
     and dispatched to the ``kernels/simjoin`` Pallas kernel on that
     node's device: compiled (``interpret=False``) on the chip, interpret
     mode only when the mesh's devices are CPU devices
-    (``simjoin.interpret_mode``). The whole dispatch is timed as
-    ``dispatch_s``; with telemetry on, each batch's steps are traced as
-    ``dispatch.lookup`` / ``dispatch.put`` / ``dispatch.launch`` /
-    ``dispatch.wait`` / ``dispatch.readback`` spans, and every query counts its launches and
-    placed bytes (``dispatch_launches`` / ``dispatch_put_bytes``).
+    (``simjoin.interpret_mode``). A query's misses are placed in one
+    transfer per device, every kernel is enqueued, and all counts are
+    read back after one wait. The whole dispatch is timed as
+    ``dispatch_s``; with telemetry on, each batch's ``dispatch.lookup``
+    and ``dispatch.launch`` are spans, and so are the query's one
+    ``dispatch.put`` (misses only), ``dispatch.wait`` and
+    ``dispatch.readback``. Every query counts its launches, placed bytes
+    and host syncs (``dispatch_launches`` / ``dispatch_put_bytes`` /
+    ``dispatch_host_syncs``).
 
 With fewer devices than nodes the node axis wraps (node ``k`` lives on
 device ``k % n_devices``): 8 nodes on one chip share its device. On
@@ -38,6 +42,7 @@ fields are additive, never substitutes.
 """
 from __future__ import annotations
 
+import itertools
 import time
 import warnings
 from typing import (TYPE_CHECKING, Any, Callable, Dict, List, Optional,
@@ -428,62 +433,93 @@ class JaxMeshBackend(SimulatedBackend):
             keys.append((ka, kb))
         return (dev, batch.fn_key, int(eps), tuple(keys))
 
+    def _pin(self, ckey: tuple, arrays: tuple) -> None:
+        """Make ``arrays`` the newest pinned entry under ``ckey`` (moved
+        to the end of the LRU order if already there), index it under
+        its chunks, and evict down to the cap."""
+        self._pinned.pop(ckey, None)
+        self._pinned[ckey] = arrays
+        for ka, kb in ckey[3]:
+            self._pinned_by_chunk.setdefault(ka[0], set()).add(ckey)
+            self._pinned_by_chunk.setdefault(kb[0], set()).add(ckey)
+        self._enforce_pinned_cap()
+
     def _count_tasks(self, tasks, eps: int
                      ) -> Tuple[List[int], Dict[str, int]]:
-        """Shape-bucketed per-node Pallas dispatch: every bucket's stacked
-        batch (dense or block-sparse per the executor's ``prune`` knob)
-        is placed on its node's device before the kernel call — ONCE per
-        resident chunk set: device-placed stacks are pinned per
-        (device, batch content) and re-dispatched directly on repeat
-        queries, invalidated with their chunks' residency. Returns the
-        per-task match counts and the query's counters: the executor's
-        prep stats plus ``dispatch_s`` (wall-clock of the loop below),
-        ``dispatch_launches`` and ``dispatch_put_bytes``."""
+        """Shape-bucketed per-node Pallas dispatch with one host sync per
+        query. Every bucket's stacked batch (dense or block-sparse per
+        the executor's ``prune`` knob) runs on its node's device, in
+        three phases: look each batch up among the pinned device stacks
+        (placed ONCE per resident chunk set, keyed per (device, batch
+        content), invalidated with their chunks' residency) and place
+        all misses with one unwaited ``jax.device_put`` per device;
+        enqueue every kernel (each is ordered after its inputs'
+        transfer); then wait once and read every count back in one
+        ``jax.device_get``. Returns the per-task match counts and the
+        query's counters: the executor's prep stats plus ``dispatch_s``
+        (wall-clock of the three phases), ``dispatch_launches``,
+        ``dispatch_put_bytes`` and ``dispatch_host_syncs``."""
         import jax
-        import jax.numpy as jnp
         counts = [0] * len(tasks)
         batches, stats = self.executor.iter_batches(tasks, eps,
                                                     by_node=True)
         tracer = self.telemetry.tracer
         dispatch_span = tracer.begin("dispatch", batches=len(batches))
-        put_bytes = 0
         t0_all = time.perf_counter()
-        for batch in batches:
+        # Every batch's device arrays, held here until all launches are
+        # issued: pinning this query's misses can evict its own earlier
+        # entries from ``_pinned`` (more batches than the cap).
+        placed: List[Optional[tuple]] = []
+        keys: List[Optional[tuple]] = []
+        misses: Dict[Any, List[int]] = {}
+        for j, batch in enumerate(batches):
             with tracer.span("dispatch.lookup"):
                 dev = self.device_for_node(batch.node)
                 ckey = self._pinned_key(batch, tasks, eps, dev)
-                arrays = (self._pinned.pop(ckey, None) if ckey is not None
+                arrays = (self._pinned.get(ckey) if ckey is not None
                           else None)
                 if arrays is not None:
                     self.device_stats["pinned_batch_hits"] += 1
-                    self._pinned[ckey] = arrays  # LRU refresh (reinsert)
-                    self._enforce_pinned_cap()
-            if arrays is None:
-                with tracer.span("dispatch.put"):
-                    arrays = tuple(jax.device_put(jnp.asarray(x), dev)
-                                   for x in batch.arrays)
-                    for x in arrays:
-                        x.block_until_ready()
-                    put_bytes += sum(x.nbytes for x in arrays)
+                    self._pin(ckey, arrays)  # LRU refresh
+                else:
+                    misses.setdefault(dev, []).append(j)
                     if ckey is not None:
                         self.device_stats["pinned_batch_misses"] += 1
-                        self._pinned[ckey] = arrays
-                        for ka, kb in ckey[3]:
-                            self._pinned_by_chunk.setdefault(
-                                ka[0], set()).add(ckey)
-                            self._pinned_by_chunk.setdefault(
-                                kb[0], set()).add(ckey)
-                        self._enforce_pinned_cap()
+                placed.append(arrays)
+                keys.append(ckey)
+        put_bytes = 0
+        if misses:
+            with tracer.span("dispatch.put"):
+                for dev, idxs in misses.items():
+                    flat = jax.device_put(
+                        [x for j in idxs for x in batches[j].arrays], dev)
+                    put_bytes += sum(x.nbytes for x in flat)
+                    it = iter(flat)
+                    for j in idxs:
+                        placed[j] = tuple(itertools.islice(
+                            it, len(batches[j].arrays)))
+                # Re-pin every cacheable batch in batch order, so the
+                # LRU order a query leaves is that of a one-batch-at-a-
+                # time loop.
+                for ckey, arrays in zip(keys, placed):
+                    if ckey is not None:
+                        self._pin(ckey, arrays)
+        results = []
+        for batch, arrays in zip(batches, placed):
             with tracer.span("dispatch.launch"):
-                got = self.executor.dispatch(batch, eps, arrays=arrays)
+                results.append(self.executor.dispatch(batch, eps,
+                                                      arrays=arrays))
+        if results:
             with tracer.span("dispatch.wait"):
-                got.block_until_ready()
+                jax.block_until_ready(results)
             with tracer.span("dispatch.readback"):
-                for i, c in zip(batch.idxs, np.asarray(got)):
-                    counts[i] = int(c)
+                for batch, got in zip(batches, jax.device_get(results)):
+                    for i, c in zip(batch.idxs, got):
+                        counts[i] = int(c)
         stats["dispatch_s"] = time.perf_counter() - t0_all
         stats["dispatch_launches"] = len(batches)
         stats["dispatch_put_bytes"] = put_bytes
+        stats["dispatch_host_syncs"] = int(bool(results))
         tracer.end(dispatch_span)
         if self.telemetry.enabled:
             self._mirror_device_stats()
@@ -583,6 +619,7 @@ class JaxMeshBackend(SimulatedBackend):
             bitmap_build_s=stats.get("bitmap_build_s"),
             dispatch_launches=stats.get("dispatch_launches", 0),
             dispatch_put_bytes=stats.get("dispatch_put_bytes", 0),
+            dispatch_host_syncs=stats.get("dispatch_host_syncs", 0),
             **self._io_fields(),
             **self._resilience_fields(report),
             **self._fault_fields(degraded)))

@@ -658,6 +658,7 @@ class SimulatedBackend:
                 bitmap_build_s=stats.get("bitmap_build_s"),
                 dispatch_launches=stats.get("dispatch_launches"),
                 dispatch_put_bytes=stats.get("dispatch_put_bytes"),
+                dispatch_host_syncs=stats.get("dispatch_host_syncs"),
                 mqo_tasks_total=total, mqo_tasks_executed=executed,
                 mqo_shared_hits=shared,
                 **self._io_fields(),

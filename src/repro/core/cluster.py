@@ -69,7 +69,8 @@ __all__ = ["BACKENDS", "CostModel", "ExecutedQuery", "JOIN_BACKENDS",
 
 #: ``ExecutedQuery`` counters copied onto each ``query`` span's args.
 QUERY_COUNTERS = ("dispatch_launches", "dispatch_put_bytes",
-                  "device_sync_bytes", "scan_read_bytes")
+                  "dispatch_host_syncs", "device_sync_bytes",
+                  "scan_read_bytes")
 
 
 class RawArrayCluster:

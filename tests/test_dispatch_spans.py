@@ -2,25 +2,30 @@
 residency sync and the raw reads.
 
 A small ``jax_mesh`` cluster over raw files read from disk runs a few
-queries with telemetry on. Each query's dispatch is split into
-``dispatch.lookup`` / ``dispatch.put`` / ``dispatch.launch`` /
-``dispatch.wait`` / ``dispatch.readback`` spans; the policy round's device reconcile is a
-``device.sync`` span, task gathering a ``gather`` span and each
-first-touch file read a ``scan.read`` span. The per-query counters
-(``dispatch_launches``, ``dispatch_put_bytes``, ``device_sync_bytes``,
-``scan_read_bytes``) are the same with telemetry off.
+queries with telemetry on. Each query's dispatch is split into one
+``dispatch.lookup`` and one ``dispatch.launch`` span per batch, and one
+``dispatch.put`` (misses only), ``dispatch.wait`` and
+``dispatch.readback`` span per query; the policy round's device
+reconcile is a ``device.sync`` span, task gathering a ``gather`` span
+and each first-touch file read a ``scan.read`` span. The per-query
+counters (``dispatch_launches``, ``dispatch_put_bytes``,
+``dispatch_host_syncs``, ``device_sync_bytes``, ``scan_read_bytes``)
+are the same with telemetry off.
 """
 import glob
 import os
 import tempfile
 
+import numpy as np
 import pytest
 
 jax = pytest.importorskip("jax")
 
 from repro.arrayio.catalog import FileReader, build_catalog
 from repro.arrayio.generator import make_ptf_files
-from repro.core.cluster import QUERY_COUNTERS, RawArrayCluster
+from repro.core.cluster import (QUERY_COUNTERS, RawArrayCluster,
+                                count_similar_pairs_np)
+from repro.core.geometry import points_in_box
 from repro.core.workload import ptf2_workload
 from repro.obs import NULL_TRACER, Tracer, last_recording
 
@@ -31,8 +36,12 @@ SUBSPANS = ("dispatch.lookup", "dispatch.put", "dispatch.launch",
 
 
 @pytest.fixture(scope="module")
-def catalog():
-    files = make_ptf_files(n_files=8, cells_per_file_mean=900, seed=21)
+def files():
+    return make_ptf_files(n_files=8, cells_per_file_mean=900, seed=21)
+
+
+@pytest.fixture(scope="module")
+def catalog(files):
     cat, _ = build_catalog(files, tempfile.mkdtemp(prefix="dspans_"),
                            "hdf5", n_nodes=N_NODES, in_memory=False)
     return cat
@@ -91,21 +100,25 @@ def test_launch_spans_count_dispatch_launches(traced):
     cl, executed = traced
     groups = query_spans(cl.telemetry.tracer)
     assert len(groups) == len(executed)
+    assert any(e.dispatch_launches > 1 for e in executed)
     for (_, spans), e in zip(groups, executed):
-        per = {n: sum(1 for s in spans if s.name == n)
-               for n in ("dispatch.lookup", "dispatch.launch",
-                         "dispatch.wait", "dispatch.readback")}
-        assert set(per.values()) == {e.dispatch_launches}
-        puts = sum(1 for s in spans if s.name == "dispatch.put")
-        assert (puts == 0) == (e.dispatch_put_bytes == 0)
+        per = {n: sum(1 for s in spans if s.name == n) for n in SUBSPANS}
+        # Per batch: a lookup and a launch. Per query: one put when
+        # anything was placed, one wait and one readback when anything
+        # was launched.
+        assert per["dispatch.lookup"] == per["dispatch.launch"] \
+            == e.dispatch_launches
+        assert per["dispatch.put"] == int(e.dispatch_put_bytes > 0)
+        assert per["dispatch.wait"] == per["dispatch.readback"] \
+            == int(e.dispatch_launches > 0)
 
 
 def test_put_bytes_count_pinned_misses(catalog, traced):
     cl, executed = traced
     puts = sum(1 for s in cl.telemetry.tracer.spans
                if s.name == "dispatch.put")
-    assert puts == cl.backend.device_stats["pinned_batch_misses"] > 0
-    assert sum(e.dispatch_put_bytes for e in executed) > 0
+    assert puts == sum(1 for e in executed if e.dispatch_put_bytes) > 0
+    assert cl.backend.device_stats["pinned_batch_misses"] > puts
     # The same query at once again re-dispatches its pinned batches:
     # kernel launches, but nothing placed.
     q = next(q for q, e in zip(queries_of(catalog), executed)
@@ -115,6 +128,37 @@ def test_put_bytes_count_pinned_misses(catalog, traced):
     assert first.dispatch_put_bytes > 0
     assert again.dispatch_launches == first.dispatch_launches > 0
     assert again.dispatch_put_bytes == 0
+
+
+def test_host_syncs_once_per_query_with_launches(catalog, traced):
+    cl, on = traced
+    quiet = make(catalog, "off")
+    off = [quiet.run_query(q) for q in queries_of(catalog)]
+    for executed in (on, off):
+        assert {e.dispatch_host_syncs for e in executed} == {0, 1}
+        for e in executed:
+            assert e.dispatch_host_syncs == int(e.dispatch_launches > 0)
+    for (q, _), e in zip(query_spans(cl.telemetry.tracer), on):
+        assert q.args["dispatch_host_syncs"] == e.dispatch_host_syncs
+
+
+def test_pinned_cap_below_one_query_keeps_counts_exact(files, catalog,
+                                                       traced):
+    # With a cap of one entry, pinning a query's misses evicts its own
+    # earlier batches from the cache while their kernels are still
+    # pending; the counts must not change.
+    _, default = traced
+    cl = make(catalog, "off")
+    cl.backend.pinned_batch_cap = 1
+    capped = [cl.run_query(q) for q in queries_of(catalog)]
+    assert max(e.dispatch_launches for e in capped) > 1
+    assert cl.backend.device_stats["pinned_batches_freed"] > 0
+    assert [e.matches for e in capped] == [e.matches for e in default]
+    coords = np.unique(np.concatenate([f.coords for f in files]), axis=0)
+    for q, e in zip(queries_of(catalog), capped):
+        pts = coords[points_in_box(coords, q.box)]
+        assert e.matches == count_similar_pairs_np(pts, pts, q.eps,
+                                                   same=True)
 
 
 def test_sync_and_gather_nest_under_query(traced):
