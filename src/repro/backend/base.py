@@ -76,12 +76,21 @@ class ExecutedQuery:
     # query's dispatch, bytes the post-round device reconcile
     # materialized, moved or copied since the previous query, and raw
     # bytes read from disk (first touch of a file; memoized reads and
-    # in-memory catalogs read none) since the previous query.
+    # in-memory catalogs read none) since the previous query. Across
+    # devices: the devices that got at least one of the query's
+    # launches and the launches on the busiest of them, cross-device
+    # ship transfers, and the executables the process built since the
+    # previous query (each an XLA compile or a load from the persistent
+    # compilation cache; a jit-cache hit builds none).
     dispatch_launches: Optional[int] = None
     dispatch_put_bytes: Optional[int] = None
     dispatch_host_syncs: Optional[int] = None
     device_sync_bytes: Optional[int] = None
     scan_read_bytes: Optional[int] = None
+    dispatch_devices: Optional[int] = None
+    dispatch_max_device_launches: Optional[int] = None
+    ship_transfers: Optional[int] = None
+    dispatch_backend_compiles: Optional[int] = None
     # Cell-exact bitmap-prune counters (None unless the bitmap stage ran
     # on at least one multi-block candidate this query — prune="bitmap",
     # or "auto" past its single-block fast path — so summaries of

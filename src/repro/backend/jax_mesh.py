@@ -14,8 +14,10 @@ that the simulated backend only models:
     CoverageIndex syncs on).
   * **Ship decisions** — each ``plan_join`` transfer route (chunk, src,
     dest) is replayed as an actual cross-device ``jax.device_put`` with
-    measured bytes and wall-clock (``measured_net_s`` /
-    ``measured_ship_bytes``).
+    measured bytes, wall-clock and count (``measured_net_s`` /
+    ``measured_ship_bytes`` / ``ship_transfers``); with telemetry on,
+    each transfer is a ``ship.transfer`` span and each host-to-device
+    staging of a just-scanned chunk a ``ship.stage`` span.
   * **Join compute** — each node's chunk-pair batch is shape-bucketed
     and dispatched to the ``kernels/simjoin`` Pallas kernel on that
     node's device: compiled (``interpret=False``) on the chip, interpret
@@ -28,7 +30,10 @@ that the simulated backend only models:
     ``dispatch.put`` (misses only), ``dispatch.wait`` and
     ``dispatch.readback``. Every query counts its launches, placed bytes
     and host syncs (``dispatch_launches`` / ``dispatch_put_bytes`` /
-    ``dispatch_host_syncs``).
+    ``dispatch_host_syncs``), the devices its launches went to and the
+    launches on the busiest one (``dispatch_devices`` /
+    ``dispatch_max_device_launches``), and the executables the process
+    built since the previous query (``dispatch_backend_compiles``).
 
 With fewer devices than nodes the node axis wraps (node ``k`` lives on
 device ``k % n_devices``): 8 nodes on one chip share its device. On
@@ -42,6 +47,7 @@ fields are additive, never substitutes.
 """
 from __future__ import annotations
 
+import collections
 import itertools
 import time
 import warnings
@@ -60,6 +66,32 @@ from repro.backend.base import BACKENDS, ExecutedQuery
 from repro.backend.cost_model import CostModel
 from repro.backend.simulated import SimulatedBackend
 from repro.faults.errors import RetryExhaustedError
+
+#: JAX's event around building one executable: an XLA compile, or a
+#: load from the persistent compilation cache.
+BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_built = [0]
+_listening: List[bool] = []
+
+
+def _on_duration_event(event: str, duration_secs: float, **kwargs) -> None:
+    """Count :data:`BACKEND_COMPILE_EVENT` (a ``jax.monitoring``
+    duration listener)."""
+    if event == BACKEND_COMPILE_EVENT:
+        _built[0] += 1
+
+
+def backend_compiles() -> int:
+    """Executables this process has built since the first call, which
+    registers the one listener that counts them. Compile caches are the
+    process's, so the count is too; a jit-cache hit builds nothing, and
+    a kernel shape dispatched to a device it never ran on builds one."""
+    if not _listening:
+        import jax.monitoring
+        jax.monitoring.register_event_duration_secs_listener(
+            _on_duration_event)
+        _listening.append(True)
+    return _built[0]
 
 
 class JaxMeshBackend(SimulatedBackend):
@@ -94,6 +126,7 @@ class JaxMeshBackend(SimulatedBackend):
                 f"k % {len(self.devices)}).{hint}", RuntimeWarning,
                 stacklevel=2)
         self.mesh = Mesh(np.array(self.devices), ("node",))
+        backend_compiles()
         # Committed cache buffers, one per replica copy: chunk id ->
         # {holder node -> device array}. ``_buffer_node`` tracks the
         # PRIMARY holder (the CacheState ``primary_map`` view the parity
@@ -379,9 +412,18 @@ class JaxMeshBackend(SimulatedBackend):
                      coords_of: Callable[[int], np.ndarray],
                      skip: Optional[set] = None
                      ) -> Tuple[float, int]:
-        """The transfer-replay loop behind :meth:`_ship`."""
+        """The transfer-replay loop behind :meth:`_ship`. Each
+        cross-device route is one waited ``jax.device_put`` of the
+        chunk's payload to the destination device (a ``ship.transfer``
+        span); the returned seconds (``measured_net_s``) are those
+        transfers and their waits, and nothing else. A payload with no
+        committed buffer at the source (a just-scanned chunk, or a
+        sliced extent under semantic reuse) is first staged onto the
+        source device from the host and waited on: that staging is the
+        ``ship.stage`` span, outside the returned seconds."""
         import jax
         import jax.numpy as jnp
+        tracer = self.telemetry.tracer
         total_s, total_b = 0.0, 0
         n_transfers = 0
         staged: Dict[int, Any] = {}
@@ -403,14 +445,16 @@ class JaxMeshBackend(SimulatedBackend):
                 if not reuse_on and src in self._buffers.get(cid, {}):
                     payload = self._buffers[cid][src]
                 else:
-                    payload = jax.device_put(
-                        jnp.asarray(coords_of(cid), jnp.int32), src_dev)
-                    payload.block_until_ready()
+                    with tracer.span("ship.stage"):
+                        payload = jax.device_put(
+                            jnp.asarray(coords_of(cid), jnp.int32), src_dev)
+                        payload.block_until_ready()
                 staged[cid] = payload
-            t0 = time.perf_counter()
-            shipped = jax.device_put(payload, dst_dev)
-            shipped.block_until_ready()
-            total_s += time.perf_counter() - t0
+            with tracer.span("ship.transfer"):
+                t0 = time.perf_counter()
+                shipped = jax.device_put(payload, dst_dev)
+                shipped.block_until_ready()
+                total_s += time.perf_counter() - t0
             total_b += int(payload.nbytes)
             n_transfers += 1
         self.device_stats["ship_bytes_measured"] += total_b
@@ -458,7 +502,8 @@ class JaxMeshBackend(SimulatedBackend):
         ``jax.device_get``. Returns the per-task match counts and the
         query's counters: the executor's prep stats plus ``dispatch_s``
         (wall-clock of the three phases), ``dispatch_launches``,
-        ``dispatch_put_bytes`` and ``dispatch_host_syncs``."""
+        ``dispatch_put_bytes``, ``dispatch_host_syncs``,
+        ``dispatch_devices`` and ``dispatch_max_device_launches``."""
         import jax
         counts = [0] * len(tasks)
         batches, stats = self.executor.iter_batches(tasks, eps,
@@ -520,28 +565,40 @@ class JaxMeshBackend(SimulatedBackend):
         stats["dispatch_launches"] = len(batches)
         stats["dispatch_put_bytes"] = put_bytes
         stats["dispatch_host_syncs"] = int(bool(results))
+        per_device = collections.Counter(
+            self.device_for_node(b.node) for b in batches)
+        stats["dispatch_devices"] = len(per_device)
+        stats["dispatch_max_device_launches"] = max(per_device.values(),
+                                                    default=0)
         tracer.end(dispatch_span)
         if self.telemetry.enabled:
             self._mirror_device_stats()
         return counts, stats
 
-    def _io_totals(self) -> Tuple[float, int]:
-        """Cumulative (bytes the device reconcile materialized, moved or
-        copied; raw bytes the coordinator's reader read from disk)."""
+    def _io_totals(self) -> Dict[str, float]:
+        """Cumulative counts behind :meth:`_io_fields`: bytes the device
+        reconcile materialized, moved or copied; raw bytes the
+        coordinator's reader read from disk; cross-device ship
+        transfers; executables the process built."""
         d = self.device_stats
-        synced = (d["committed_bytes_materialized"]
-                  + d["committed_bytes_moved"] + d["replica_bytes_copied"])
-        return synced, getattr(self.coordinator.reader, "bytes_read", 0)
+        return {
+            "device_sync_bytes": (d["committed_bytes_materialized"]
+                                  + d["committed_bytes_moved"]
+                                  + d["replica_bytes_copied"]),
+            "scan_read_bytes": getattr(self.coordinator.reader,
+                                       "bytes_read", 0),
+            "ship_transfers": d["ship_transfers"],
+            "dispatch_backend_compiles": backend_compiles()}
 
     def _io_fields(self) -> Dict[str, int]:
-        """``device_sync_bytes`` / ``scan_read_bytes`` of the query being
-        built: the growth of :meth:`_io_totals` since the previous one
-        (a batch's policy round and scans land on its first query)."""
+        """``device_sync_bytes``, ``scan_read_bytes``, ``ship_transfers``
+        and ``dispatch_backend_compiles`` of the query being built: the
+        growth of :meth:`_io_totals` since the previous one (a batch's
+        policy round and scans land on its first query)."""
         now = self._io_totals()
-        synced, read = (a - b for a, b in zip(now, self._io_seen))
+        out = {k: int(v - self._io_seen[k]) for k, v in now.items()}
         self._io_seen = now
-        return {"device_sync_bytes": int(synced),
-                "scan_read_bytes": int(read)}
+        return out
 
     def _measured_ship(self, query: "SimilarityJoinQuery",
                        report: "QueryReport",
@@ -620,6 +677,9 @@ class JaxMeshBackend(SimulatedBackend):
             dispatch_launches=stats.get("dispatch_launches", 0),
             dispatch_put_bytes=stats.get("dispatch_put_bytes", 0),
             dispatch_host_syncs=stats.get("dispatch_host_syncs", 0),
+            dispatch_devices=stats.get("dispatch_devices", 0),
+            dispatch_max_device_launches=stats.get(
+                "dispatch_max_device_launches", 0),
             **self._io_fields(),
             **self._resilience_fields(report),
             **self._fault_fields(degraded)))

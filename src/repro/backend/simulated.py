@@ -659,6 +659,9 @@ class SimulatedBackend:
                 dispatch_launches=stats.get("dispatch_launches"),
                 dispatch_put_bytes=stats.get("dispatch_put_bytes"),
                 dispatch_host_syncs=stats.get("dispatch_host_syncs"),
+                dispatch_devices=stats.get("dispatch_devices"),
+                dispatch_max_device_launches=stats.get(
+                    "dispatch_max_device_launches"),
                 mqo_tasks_total=total, mqo_tasks_executed=executed,
                 mqo_shared_hits=shared,
                 **self._io_fields(),

@@ -70,7 +70,9 @@ __all__ = ["BACKENDS", "CostModel", "ExecutedQuery", "JOIN_BACKENDS",
 #: ``ExecutedQuery`` counters copied onto each ``query`` span's args.
 QUERY_COUNTERS = ("dispatch_launches", "dispatch_put_bytes",
                   "dispatch_host_syncs", "device_sync_bytes",
-                  "scan_read_bytes")
+                  "scan_read_bytes", "dispatch_devices",
+                  "dispatch_max_device_launches", "measured_ship_bytes",
+                  "ship_transfers", "dispatch_backend_compiles")
 
 
 class RawArrayCluster:

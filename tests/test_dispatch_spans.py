@@ -9,8 +9,12 @@ queries with telemetry on. Each query's dispatch is split into one
 reconcile is a ``device.sync`` span, task gathering a ``gather`` span
 and each first-touch file read a ``scan.read`` span. The per-query
 counters (``dispatch_launches``, ``dispatch_put_bytes``,
-``dispatch_host_syncs``, ``device_sync_bytes``, ``scan_read_bytes``)
-are the same with telemetry off.
+``dispatch_host_syncs``, ``device_sync_bytes``, ``scan_read_bytes``,
+``dispatch_devices``, ``dispatch_max_device_launches``,
+``measured_ship_bytes``, ``ship_transfers``) are the same with
+telemetry off; ``dispatch_backend_compiles`` counts the executables
+the process built. On four devices each cross-device ship route is a
+``ship.transfer`` span and each staged payload a ``ship.stage`` span.
 """
 import glob
 import os
@@ -25,6 +29,7 @@ from repro.arrayio.catalog import FileReader, build_catalog
 from repro.arrayio.generator import make_ptf_files
 from repro.core.cluster import (QUERY_COUNTERS, RawArrayCluster,
                                 count_similar_pairs_np)
+from repro.core.coordinator import SimilarityJoinQuery
 from repro.core.geometry import points_in_box
 from repro.core.workload import ptf2_workload
 from repro.obs import NULL_TRACER, Tracer, last_recording
@@ -61,11 +66,15 @@ def make(catalog, telemetry):
                            telemetry=telemetry)
 
 
-@pytest.fixture(scope="module")
-def traced(catalog):
+def traced_run(catalog):
     cl = make(catalog, "on")
     executed = [cl.run_query(q) for q in queries_of(catalog)]
     return cl, executed
+
+
+@pytest.fixture(scope="module")
+def traced(catalog):
+    return traced_run(catalog)
 
 
 def query_spans(tracer):
@@ -212,7 +221,9 @@ def test_telemetry_off_records_nothing_and_counts_the_same(catalog,
     off = [cl.run_query(q) for q in queries_of(catalog)]
     assert cl.telemetry.tracer is NULL_TRACER and not NULL_TRACER.spans
     assert [e.matches for e in off] == [e.matches for e in on]
-    for key in QUERY_COUNTERS:
+    # Executables built depend on what the process compiled before (the
+    # traced run already built this workload's), not on telemetry.
+    for key in set(QUERY_COUNTERS) - {"dispatch_backend_compiles"}:
         assert [getattr(e, key) for e in off] == \
             [getattr(e, key) for e in on], key
 
@@ -264,3 +275,50 @@ def test_spans_mirror_into_the_profiler_trace(catalog, tmp_path):
         spans[s.name] = spans.get(s.name, 0) + 1
     for name, n in spans.items():
         assert host.get(name) == n, name
+
+
+def test_backend_compiles_count_a_forced_recompile(catalog):
+    """A query whose kernels are all built reads 0; the same box at a
+    new eps (a static argument of the kernel) builds its kernels again,
+    and a repeat of it reads 0."""
+    cl = make(catalog, "off")
+    qs = queries_of(catalog)
+    last = [cl.run_query(q) for q in qs + qs][-4:]
+    q = qs[max(range(4), key=lambda k: last[k].dispatch_launches)]
+    again = cl.run_query(q)
+    assert again.dispatch_launches > 0
+    assert again.dispatch_backend_compiles == 0
+    odd = SimilarityJoinQuery(box=q.box, eps=q.eps + 17)
+    first = cl.run_query(odd)
+    assert first.dispatch_launches > 0
+    assert first.dispatch_backend_compiles >= 1
+    assert cl.run_query(odd).dispatch_backend_compiles == 0
+
+
+def test_ship_spans_and_device_counters_on_four_devices(
+        catalog, rerun_on_cpu_devices):
+    """With a device per node, every cross-device ship route is one
+    ``ship.transfer`` span under ``ship`` and counted in
+    ``ship_transfers``; launches spread over devices, each query's
+    busiest device holds at least its share of them."""
+    if len(jax.devices()) < N_NODES:
+        rerun_on_cpu_devices(
+            f"{__file__}::test_ship_spans_and_device_counters_on_four_devices",
+            N_NODES)
+        return
+    cl, executed = traced_run(catalog)
+    by_id = {s.span_id: s for s in cl.telemetry.tracer.spans}
+    for (q, spans), e in zip(query_spans(cl.telemetry.tracer), executed):
+        names = [s.name for s in spans]
+        assert names.count("ship.transfer") == e.ship_transfers
+        assert names.count("ship.stage") <= e.ship_transfers
+        for s in spans:
+            if s.name in ("ship.transfer", "ship.stage"):
+                assert by_id[s.parent_id].name == "ship"
+        assert (e.measured_ship_bytes > 0) == (e.ship_transfers > 0)
+        assert e.dispatch_devices <= N_NODES
+        assert (e.dispatch_max_device_launches * max(e.dispatch_devices, 1)
+                >= e.dispatch_launches >= e.dispatch_max_device_launches)
+    assert sum(e.ship_transfers for e in executed) \
+        == cl.backend.device_stats["ship_transfers"] > 0
+    assert max(e.dispatch_devices for e in executed) > 1
