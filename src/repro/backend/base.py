@@ -71,7 +71,8 @@ class ExecutedQuery:
     artifact_misses: Optional[int] = None
     # Device-path counters of the mesh backend (None under the simulated
     # backend; not summed into ``workload_summary``): jitted kernel calls
-    # made, bytes placed on the device for pinned-batch misses and times
+    # made, the batches among them served from pinned device stacks,
+    # bytes placed on the device for pinned-batch misses and times
     # the host blocked on the device (1 with launches, else 0) by this
     # query's dispatch, bytes the post-round device reconcile
     # materialized, moved or copied since the previous query, and raw
@@ -83,6 +84,7 @@ class ExecutedQuery:
     # previous query (each an XLA compile or a load from the persistent
     # compilation cache; a jit-cache hit builds none).
     dispatch_launches: Optional[int] = None
+    dispatch_pinned_hits: Optional[int] = None
     dispatch_put_bytes: Optional[int] = None
     dispatch_host_syncs: Optional[int] = None
     device_sync_bytes: Optional[int] = None

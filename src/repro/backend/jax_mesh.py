@@ -28,12 +28,14 @@ that the simulated backend only models:
     ``dispatch_s``; with telemetry on, each batch's ``dispatch.lookup``
     and ``dispatch.launch`` are spans, and so are the query's one
     ``dispatch.put`` (misses only), ``dispatch.wait`` and
-    ``dispatch.readback``. Every query counts its launches, placed bytes
-    and host syncs (``dispatch_launches`` / ``dispatch_put_bytes`` /
-    ``dispatch_host_syncs``), the devices its launches went to and the
-    launches on the busiest one (``dispatch_devices`` /
-    ``dispatch_max_device_launches``), and the executables the process
-    built since the previous query (``dispatch_backend_compiles``).
+    ``dispatch.readback``. Every query counts its launches, the batches
+    it found among the pinned device stacks, placed bytes and host syncs
+    (``dispatch_launches`` / ``dispatch_pinned_hits`` /
+    ``dispatch_put_bytes`` / ``dispatch_host_syncs``), the devices its
+    launches went to and the launches on the busiest one
+    (``dispatch_devices`` / ``dispatch_max_device_launches``), and the
+    executables the process built since the previous query
+    (``dispatch_backend_compiles``).
 
 With fewer devices than nodes the node axis wraps (node ``k`` lives on
 device ``k % n_devices``): 8 nodes on one chip share its device. On
@@ -94,6 +96,25 @@ def backend_compiles() -> int:
     return _built[0]
 
 
+#: Where a device reports no memory limit (a CPU device), each device's
+#: pinned-batch budget: about a quarter of one v5e chip's 16 GB.
+PINNED_BYTES_UNREPORTED = 4 << 30
+
+
+def pinned_budget(devices: Sequence[Any]) -> int:
+    """Bytes of pinned dispatch batches each device may hold: a quarter
+    of the smallest ``bytes_limit`` the devices report, or
+    :data:`PINNED_BYTES_UNREPORTED` where none reports one."""
+    limits = [(d.memory_stats() or {}).get("bytes_limit")
+              for d in devices]
+    limits = [int(b) for b in limits if b]
+    return min(limits) // 4 if limits else PINNED_BYTES_UNREPORTED
+
+
+def _nbytes(arrays: tuple) -> int:
+    return sum(int(x.nbytes) for x in arrays)
+
+
 class JaxMeshBackend(SimulatedBackend):
     """Execution over a one-axis device mesh: nodes -> jax devices."""
 
@@ -141,12 +162,14 @@ class JaxMeshBackend(SimulatedBackend):
         # over resident chunks re-dispatches the SAME device buffers
         # instead of re-device_put-ting identical host stacks; entries
         # are invalidated with the chunks they were stacked from, and —
-        # because they live in device memory, the scarcest resource —
-        # additionally LRU-capped at ``pinned_batch_cap`` entries
-        # (insertion order of the dict, refreshed on hit).
+        # because they live in device memory — each device's entries
+        # are evicted least recently used first (insertion order of the
+        # dict, refreshed on hit) while their bytes exceed
+        # ``_pinned_budget`` (see :func:`pinned_budget`).
         self._pinned: Dict[tuple, tuple] = {}
         self._pinned_by_chunk: Dict[int, set] = {}
-        self.pinned_batch_cap = 256
+        self._pinned_bytes: Dict[Any, int] = {}
+        self._pinned_budget = pinned_budget(self.devices)
         # Cumulative device-side counters (bench_scalability surfaces them).
         self.device_stats: Dict[str, float] = {
             "committed_bytes_materialized": 0.0,
@@ -212,17 +235,12 @@ class JaxMeshBackend(SimulatedBackend):
 
     # ------------------------- DeviceBindingListener (cache life-cycle) --
 
-    def _enforce_pinned_cap(self) -> None:
-        """Evict least-recently-used pinned batches down to the cap
-        (dict insertion order, refreshed on every hit)."""
-        while len(self._pinned) > self.pinned_batch_cap:
-            old = next(iter(self._pinned))
-            del self._pinned[old]
-            self.device_stats["pinned_batches_freed"] += 1
-            self._unindex_pinned(old)
-
-    def _unindex_pinned(self, key: tuple) -> None:
-        """Remove a freed pinned entry from every chunk's key set."""
+    def _free_pinned(self, key: tuple) -> None:
+        """Free one pinned batch: take its bytes off its device's count
+        and its key out of every chunk's key set."""
+        arrays = self._pinned.pop(key)
+        self._pinned_bytes[key[0]] -= _nbytes(arrays)
+        self.device_stats["pinned_batches_freed"] += 1
         for ka, kb in key[3]:
             for cid in (ka[0], kb[0]):
                 refs = self._pinned_by_chunk.get(cid)
@@ -235,9 +253,8 @@ class JaxMeshBackend(SimulatedBackend):
         """Free every pinned dispatch batch stacked from a chunk (and
         unindex it from the partner chunks' key sets)."""
         for key in self._pinned_by_chunk.pop(chunk_id, ()):
-            if self._pinned.pop(key, None) is not None:
-                self.device_stats["pinned_batches_freed"] += 1
-                self._unindex_pinned(key)
+            if key in self._pinned:
+                self._free_pinned(key)
 
     def on_drop(self, chunk_id: int) -> None:
         """Eviction/placement dropped a chunk: free the device buffer of
@@ -371,9 +388,7 @@ class JaxMeshBackend(SimulatedBackend):
                 self._buffer_node[cid] = next(iter(per_node))
         dev = self.device_for_node(node)
         for key in [k for k in self._pinned if k[0] == dev]:
-            del self._pinned[key]
-            self.device_stats["pinned_batches_freed"] += 1
-            self._unindex_pinned(key)
+            self._free_pinned(key)
         return self.coordinator.fail_node(node)
 
     # ----------------------------------------------------------- execution
@@ -480,13 +495,18 @@ class JaxMeshBackend(SimulatedBackend):
     def _pin(self, ckey: tuple, arrays: tuple) -> None:
         """Make ``arrays`` the newest pinned entry under ``ckey`` (moved
         to the end of the LRU order if already there), index it under
-        its chunks, and evict down to the cap."""
-        self._pinned.pop(ckey, None)
+        its chunks, and evict the device's least recently used entries
+        while its pinned bytes exceed the budget."""
+        dev = ckey[0]
+        old = self._pinned.pop(ckey, ())
         self._pinned[ckey] = arrays
+        self._pinned_bytes[dev] = (self._pinned_bytes.get(dev, 0)
+                                   + _nbytes(arrays) - _nbytes(old))
         for ka, kb in ckey[3]:
             self._pinned_by_chunk.setdefault(ka[0], set()).add(ckey)
             self._pinned_by_chunk.setdefault(kb[0], set()).add(ckey)
-        self._enforce_pinned_cap()
+        while self._pinned_bytes[dev] > self._pinned_budget:
+            self._free_pinned(next(k for k in self._pinned if k[0] == dev))
 
     def _count_tasks(self, tasks, eps: int
                      ) -> Tuple[List[int], Dict[str, int]]:
@@ -502,6 +522,7 @@ class JaxMeshBackend(SimulatedBackend):
         ``jax.device_get``. Returns the per-task match counts and the
         query's counters: the executor's prep stats plus ``dispatch_s``
         (wall-clock of the three phases), ``dispatch_launches``,
+        ``dispatch_pinned_hits`` (batches served from pinned stacks),
         ``dispatch_put_bytes``, ``dispatch_host_syncs``,
         ``dispatch_devices`` and ``dispatch_max_device_launches``."""
         import jax
@@ -513,10 +534,11 @@ class JaxMeshBackend(SimulatedBackend):
         t0_all = time.perf_counter()
         # Every batch's device arrays, held here until all launches are
         # issued: pinning this query's misses can evict its own earlier
-        # entries from ``_pinned`` (more batches than the cap).
+        # entries from ``_pinned`` (more bytes than the budget).
         placed: List[Optional[tuple]] = []
         keys: List[Optional[tuple]] = []
         misses: Dict[Any, List[int]] = {}
+        hits = 0
         for j, batch in enumerate(batches):
             with tracer.span("dispatch.lookup"):
                 dev = self.device_for_node(batch.node)
@@ -524,6 +546,7 @@ class JaxMeshBackend(SimulatedBackend):
                 arrays = (self._pinned.get(ckey) if ckey is not None
                           else None)
                 if arrays is not None:
+                    hits += 1
                     self.device_stats["pinned_batch_hits"] += 1
                     self._pin(ckey, arrays)  # LRU refresh
                 else:
@@ -563,6 +586,7 @@ class JaxMeshBackend(SimulatedBackend):
                         counts[i] = int(c)
         stats["dispatch_s"] = time.perf_counter() - t0_all
         stats["dispatch_launches"] = len(batches)
+        stats["dispatch_pinned_hits"] = hits
         stats["dispatch_put_bytes"] = put_bytes
         stats["dispatch_host_syncs"] = int(bool(results))
         per_device = collections.Counter(
@@ -675,6 +699,7 @@ class JaxMeshBackend(SimulatedBackend):
             block_pairs_bitmap_killed=stats.get("block_pairs_bitmap_killed"),
             bitmap_build_s=stats.get("bitmap_build_s"),
             dispatch_launches=stats.get("dispatch_launches", 0),
+            dispatch_pinned_hits=stats.get("dispatch_pinned_hits", 0),
             dispatch_put_bytes=stats.get("dispatch_put_bytes", 0),
             dispatch_host_syncs=stats.get("dispatch_host_syncs", 0),
             dispatch_devices=stats.get("dispatch_devices", 0),

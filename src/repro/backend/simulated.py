@@ -657,6 +657,7 @@ class SimulatedBackend:
                     "block_pairs_bitmap_killed"),
                 bitmap_build_s=stats.get("bitmap_build_s"),
                 dispatch_launches=stats.get("dispatch_launches"),
+                dispatch_pinned_hits=stats.get("dispatch_pinned_hits"),
                 dispatch_put_bytes=stats.get("dispatch_put_bytes"),
                 dispatch_host_syncs=stats.get("dispatch_host_syncs"),
                 dispatch_devices=stats.get("dispatch_devices"),

@@ -72,7 +72,8 @@ QUERY_COUNTERS = ("dispatch_launches", "dispatch_put_bytes",
                   "dispatch_host_syncs", "device_sync_bytes",
                   "scan_read_bytes", "dispatch_devices",
                   "dispatch_max_device_launches", "measured_ship_bytes",
-                  "ship_transfers", "dispatch_backend_compiles")
+                  "ship_transfers", "dispatch_backend_compiles",
+                  "dispatch_pinned_hits")
 
 
 class RawArrayCluster:

@@ -471,18 +471,15 @@ def test_mesh_pins_padded_batches_across_queries(dataset):
     assert backend.device_stats["pinned_batch_hits"] > 0
     cluster.coordinator.cache.sync_devices()
     assert set(backend._pinned_by_chunk) <= cluster.coordinator.cache.cached
-    # Device memory is LRU-capped: shrinking the cap and re-running
-    # evicts down to it (with the chunk index pruned alongside), while
-    # match counts stay identical.
-    backend.pinned_batch_cap = 1
+    # Device memory is budgeted in bytes per device: shrinking the
+    # budget to one byte and re-running evicts every entry (with the
+    # chunk index pruned alongside), while match counts stay identical.
+    backend._pinned_budget = 1
     again = [e.matches for e in cluster.run_workload(queries)]
     assert again == cold
-    assert len(backend._pinned) <= 1
+    assert backend._pinned == {} and backend._pinned_by_chunk == {}
+    assert set(backend._pinned_bytes.values()) == {0}
     assert backend.device_stats["pinned_batches_freed"] > 0
-    live = set()
-    for refs in backend._pinned_by_chunk.values():
-        live |= refs
-    assert live <= set(backend._pinned)
 
 
 def test_workload_summary_amortization_counters(dataset):

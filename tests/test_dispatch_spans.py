@@ -8,7 +8,8 @@ queries with telemetry on. Each query's dispatch is split into one
 ``dispatch.readback`` span per query; the policy round's device
 reconcile is a ``device.sync`` span, task gathering a ``gather`` span
 and each first-touch file read a ``scan.read`` span. The per-query
-counters (``dispatch_launches``, ``dispatch_put_bytes``,
+counters (``dispatch_launches``, ``dispatch_pinned_hits``,
+``dispatch_put_bytes``,
 ``dispatch_host_syncs``, ``device_sync_bytes``, ``scan_read_bytes``,
 ``dispatch_devices``, ``dispatch_max_device_launches``,
 ``measured_ship_bytes``, ``ship_transfers``) are the same with
@@ -19,6 +20,7 @@ the process built. On four devices each cross-device ship route is a
 import glob
 import os
 import tempfile
+from types import SimpleNamespace
 
 import numpy as np
 import pytest
@@ -27,6 +29,8 @@ jax = pytest.importorskip("jax")
 
 from repro.arrayio.catalog import FileReader, build_catalog
 from repro.arrayio.generator import make_ptf_files
+from repro.backend.jax_mesh import (PINNED_BYTES_UNREPORTED,
+                                    JaxMeshBackend, pinned_budget)
 from repro.core.cluster import (QUERY_COUNTERS, RawArrayCluster,
                                 count_similar_pairs_np)
 from repro.core.coordinator import SimilarityJoinQuery
@@ -139,6 +143,24 @@ def test_put_bytes_count_pinned_misses(catalog, traced):
     assert again.dispatch_put_bytes == 0
 
 
+@pytest.mark.parametrize("telemetry", ["on", "off"])
+def test_repeat_query_hits_every_pinned_batch(catalog, traced, telemetry):
+    # A fresh cluster has nothing pinned; the same query at once again
+    # finds every batch among the stacks its first run pinned.
+    _, executed = traced
+    q = next(q for q, e in zip(queries_of(catalog), executed)
+             if e.dispatch_launches)
+    cl = make(catalog, telemetry)
+    first, again = cl.run_query(q), cl.run_query(q)
+    assert first.dispatch_pinned_hits == 0 < first.dispatch_launches
+    assert again.dispatch_pinned_hits == again.dispatch_launches \
+        == first.dispatch_launches
+    if telemetry == "on":
+        spans = [s for s, _ in query_spans(cl.telemetry.tracer)]
+        assert [s.args["dispatch_pinned_hits"] for s in spans] == \
+            [0, again.dispatch_launches]
+
+
 def test_host_syncs_once_per_query_with_launches(catalog, traced):
     cl, on = traced
     quiet = make(catalog, "off")
@@ -153,15 +175,17 @@ def test_host_syncs_once_per_query_with_launches(catalog, traced):
 
 def test_pinned_cap_below_one_query_keeps_counts_exact(files, catalog,
                                                        traced):
-    # With a cap of one entry, pinning a query's misses evicts its own
-    # earlier batches from the cache while their kernels are still
-    # pending; the counts must not change.
+    # With a budget of one byte, pinning a query's misses evicts its own
+    # batches from the cache while their kernels are still pending; the
+    # counts must not change.
     _, default = traced
     cl = make(catalog, "off")
-    cl.backend.pinned_batch_cap = 1
+    cl.backend._pinned_budget = 1
     capped = [cl.run_query(q) for q in queries_of(catalog)]
     assert max(e.dispatch_launches for e in capped) > 1
     assert cl.backend.device_stats["pinned_batches_freed"] > 0
+    assert cl.backend._pinned == {}
+    assert all(e.dispatch_pinned_hits == 0 for e in capped)
     assert [e.matches for e in capped] == [e.matches for e in default]
     coords = np.unique(np.concatenate([f.coords for f in files]), axis=0)
     for q, e in zip(queries_of(catalog), capped):
@@ -322,3 +346,74 @@ def test_ship_spans_and_device_counters_on_four_devices(
     assert sum(e.ship_transfers for e in executed) \
         == cl.backend.device_stats["ship_transfers"] > 0
     assert max(e.dispatch_devices for e in executed) > 1
+
+
+@pytest.mark.parametrize("limits, budget", [
+    ((16 << 30, 16 << 30), 4 << 30),
+    ((16 << 30, 8 << 30), 2 << 30),
+    ((None, None), PINNED_BYTES_UNREPORTED)])
+def test_pinned_budget_is_a_quarter_of_device_memory(limits, budget):
+    devices = [SimpleNamespace(memory_stats=lambda b=b: (
+        None if b is None else {"bytes_limit": b})) for b in limits]
+    assert pinned_budget(devices) == budget
+
+
+def test_pinned_budget_evicts_lru_bytes_per_device(rerun_on_cpu_devices):
+    """More than 256 small pinned batches all stay under the derived
+    budget. Shrunk below what every device holds, the budget evicts a
+    device's least recently used batches, by bytes, when that device
+    pins, and no other device's; the chunk index is pruned alongside,
+    and dropping every chunk brings each device's count back to 0."""
+    if len(jax.devices()) < N_NODES:
+        rerun_on_cpu_devices(
+            f"{__file__}::test_pinned_budget_evicts_lru_bytes_per_device",
+            N_NODES)
+        return
+    devs = jax.devices()[:N_NODES]
+    backend = JaxMeshBackend(N_NODES, devices=devs)
+    assert backend._pinned_budget == PINNED_BYTES_UNREPORTED
+    size = {}
+
+    def pin(i):
+        dev = devs[i % N_NODES]
+        key = (dev, "simjoin", 300, (((i, 0), (1000 + i % 7, 0)),))
+        arrays = (jax.device_put(np.zeros(16 * (1 + i % 5), np.int32),
+                                 dev),)
+        backend._pin(key, arrays)
+        size[key] = arrays[0].nbytes
+        return key
+
+    keys = [pin(i) for i in range(300)]
+    assert list(backend._pinned) == keys
+    for d in devs:
+        assert backend._pinned_bytes[d] == sum(size[k] for k in keys
+                                               if k[0] == d)
+    # A hit on device 0's oldest batch makes it recent; then the budget
+    # drops below every device's bytes and device 0 pins once more.
+    backend._pin(keys[0], backend._pinned[keys[0]])
+    lru = keys[1:] + keys[:1]
+    backend._pinned_budget = min(backend._pinned_bytes.values()) // 3
+    new = pin(300)
+    want = [k for k in lru + [new] if k[0] == devs[0]]
+    held = sum(size[k] for k in want)
+    while held > backend._pinned_budget:
+        held -= size[want.pop(0)]
+    assert [k for k in backend._pinned if k[0] == devs[0]] == want
+    assert keys[0] in want and keys[N_NODES] not in want
+    assert backend._pinned_bytes[devs[0]] == held
+    for d in devs[1:]:
+        assert [k for k in backend._pinned if k[0] == d] == \
+            [k for k in lru if k[0] == d]
+        assert backend._pinned_bytes[d] > backend._pinned_budget
+    assert backend.device_stats["pinned_batches_freed"] == \
+        len(keys) + 1 - len(backend._pinned)
+    index = {}
+    for k in backend._pinned:
+        for ka, kb in k[3]:
+            index.setdefault(ka[0], set()).add(k)
+            index.setdefault(kb[0], set()).add(k)
+    assert backend._pinned_by_chunk == index
+    for cid in list(index):
+        backend.on_drop(cid)
+    assert backend._pinned == {} and backend._pinned_by_chunk == {}
+    assert set(backend._pinned_bytes.values()) == {0}
