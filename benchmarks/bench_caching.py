@@ -173,8 +173,7 @@ def to_json_summary(results: Dict, policies: Sequence[str],
                          "files_scanned", "reuse_hits", "reuse_bytes_served",
                          "residual_bytes_scanned", "reuse_scan_skips")},
             # Join-kernel block-pair counters (0.0 when joins are not
-            # executed, the bench_caching default; BENCH_kernels.json
-            # carries the executed-join pruning trajectory).
+            # executed, the bench_caching default).
             **{k: payload["summary"].get(k, 0.0)
                for k in ("block_pairs_total", "block_pairs_evaluated")},
             "policy_spec": payload["policy_spec"],

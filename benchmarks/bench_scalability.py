@@ -4,7 +4,7 @@ plus two executed-join sections:
 
   * the execution-backend comparison (``run_backends``): the same
     workload run under the simulated cost model and under the jax
-    device-mesh backend, across the dense / block-sparse / auto join
+    device-mesh backend, across the dense and block-sparse join
     grids, reporting REAL (measured, not modeled) transfer and join
     wall-clock per backend;
   * the cross-query sharing scenario (``run_mqo``): a Zipf-skewed
@@ -73,8 +73,8 @@ def run(n_queries: int = 100, print_rows: bool = True):
 def run_backends(n_queries: int = 30, print_rows: bool = True,
                  seed: int = 33) -> List[Dict]:
     """Backend comparison: identical plans executed by the simulated and
-    jax_mesh backends, each under the dense, block-sparse, and
-    adaptive-auto join grids. Returns one structured row dict per
+    jax_mesh backends, each under the dense and block-sparse join
+    grids. Returns one structured row dict per
     configuration carrying the modeled net/compute times, the block-pair
     pruning counters (``block_pairs_evaluated/total``), and for the mesh
     backend the MEASURED transfer + join kernel wall-clock and measured
@@ -88,7 +88,7 @@ def run_backends(n_queries: int = 30, print_rows: bool = True,
     rows: List[Dict] = []
     matches = {}
     for backend in ("simulated", "jax_mesh"):
-        for prune in ("dense", "block", "auto"):
+        for prune in ("dense", "block"):
             label = f"{backend}_{prune}"
             cluster = RawArrayCluster(
                 catalog, reader, N_NODES, budget // N_NODES, policy="cost",
@@ -168,7 +168,7 @@ def run_mqo(n_queries: int = 60, n_templates: int = 12,
                 cluster = RawArrayCluster(
                     catalog, reader, N_NODES, budget // N_NODES,
                     policy="cost", min_cells=48, execute_joins=True,
-                    backend=backend, join_backend="pallas", prune="auto",
+                    backend=backend, join_backend="pallas", prune="block",
                     mqo=mqo, result_cache=rc)
                 executed, us = timed(cluster.run_workload, queries,
                                      batch_size=batch_size)
@@ -233,7 +233,7 @@ def run_failover(n_queries: int = 48, n_templates: int = 6,
         return RawArrayCluster(
             catalog, reader, N_NODES, budget // N_NODES, policy="cost",
             min_cells=48, execute_joins=True, backend=backend,
-            join_backend="pallas", prune="auto", replication=replication,
+            join_backend="pallas", prune="block", replication=replication,
             replica_k=2, replication_threshold=2.0)
 
     rows: List[Dict] = []
@@ -322,7 +322,7 @@ def run_chaos(n_queries: int = 36, n_templates: int = 4,
         return RawArrayCluster(
             catalog, reader, N_NODES, budget // N_NODES, policy="cost",
             min_cells=48, execute_joins=True, backend=backend,
-            join_backend="pallas", prune="auto", replication="hot",
+            join_backend="pallas", prune="block", replication="hot",
             replica_k=2, replication_threshold=2.0, faults=faults)
 
     def p95(values: List[float]) -> float:
@@ -429,7 +429,7 @@ def run_observability(n_queries: int = 60, n_templates: int = 12,
         cluster = RawArrayCluster(
             catalog, reader, N_NODES, budget // N_NODES, policy="cost",
             min_cells=48, execute_joins=True, backend="simulated",
-            join_backend="pallas", prune="auto", reuse="on", mqo="on",
+            join_backend="pallas", prune="block", reuse="on", mqo="on",
             result_cache="on", replication="hot", telemetry=telemetry)
         # GC pauses (not the instrumentation) dominate run-to-run jitter
         # on this Python-geometry-heavy workload: collect up front and

@@ -8,9 +8,8 @@ from __future__ import annotations
 
 import sys
 
-from benchmarks import (bench_caching, bench_kernels, bench_opt_time,
-                        bench_placement, bench_prefix_cache,
-                        bench_scalability)
+from benchmarks import (bench_caching, bench_opt_time, bench_placement,
+                        bench_prefix_cache, bench_scalability)
 
 SECTIONS = {
     "fig5": ("Fig 5: caching strategies x budgets x formats",
@@ -20,7 +19,6 @@ SECTIONS = {
     "fig7": ("Fig 7: optimization time (chunking / evict+place)",
              bench_opt_time.run),
     "fig8": ("Fig 8: placement static vs dynamic", bench_placement.run),
-    "kernels": ("Pallas kernels (interpret mode)", bench_kernels.run),
     "prefix": ("KV prefix cache: cost vs LRU", bench_prefix_cache.run),
 }
 

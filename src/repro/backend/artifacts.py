@@ -15,14 +15,7 @@ recomputed over identical inputs again and again.
     sentinel sign, i.e. per join side);
   * per ``(chunk_a, chunk_b, block, eps, same)`` — the pruned
     block-pair list from ``prune.build_block_pairs`` together with its
-    dense-grid denominator;
-  * per ``(chunk, queried-subset, block, scale)`` — the hierarchical
-    occupancy bitmap sidecars from ``prune.build_bitmaps`` (the
-    cell-exact prune stage's per-block quantized-cell sets);
-  * per ``(chunk_a, chunk_b, block, eps, same)`` again under a distinct
-    ``"bpair"`` tag — the bitmap-refined pair list from
-    ``prune.refine_block_pairs`` with its killed-pair count, so warm
-    queries skip the refinement pass along with the rest of prep.
+    dense-grid denominator.
 
 Keying is *content-addressed through residency*: a chunk id's cell set
 never changes while the id is live (splits retire the parent id and mint
@@ -108,16 +101,13 @@ def task_coords(x) -> np.ndarray:
 class _Artifacts:
     """Lazily-filled derived arrays of one (chunk, subset) slice."""
 
-    __slots__ = ("sorted_coords", "padded", "bitmaps")
+    __slots__ = ("sorted_coords", "padded")
 
     def __init__(self):
         self.sorted_coords: Optional[np.ndarray] = None
         # sentinel value -> (d, N_padded) coordinate-major padded array
         # (one entry per join side: +sentinel for a, -sentinel for b).
         self.padded: Dict[int, np.ndarray] = {}
-        # (block, scale) -> per-block hierarchical occupancy bitmaps
-        # (list of (fine, coarse) quantized-cell arrays).
-        self.bitmaps: Dict[Tuple[int, int], list] = {}
 
 
 class JoinArtifactCache:
@@ -134,7 +124,6 @@ class JoinArtifactCache:
         self.max_subsets_per_chunk = max_subsets_per_chunk
         self._entries: Dict[ChunkKey, _Artifacts] = {}
         # ("pair", key_a, key_b, block, eps, same) -> (pairs, dense_total)
-        # ("bpair", key_a, key_b, block, eps, same) -> (refined, killed)
         self._pairs: Dict[tuple, tuple] = {}
         # chunk id -> every key (entry or pair) derived from it, so one
         # residency event invalidates all dependent artifacts.
@@ -221,49 +210,14 @@ class JoinArtifactCache:
                     ) -> Tuple[np.ndarray, int]:
         """The ``(pairs, dense_total)`` pruned block-pair list for one
         task (memoized per chunk pair, block size, eps, and join mode;
-        computed directly when either side is uncacheable)."""
-        return self._pair_artifact("pair", view_a, view_b, block, eps,
-                                   same, compute)
-
-    def bitmaps(self, view: ChunkView, block: int, scale: int,
-                compute: Callable[[], list]) -> list:
-        """The hierarchical occupancy bitmaps of a view's sorted
-        coordinates (memoized per block size and quantization scale) —
-        the per-block ``(fine, coarse)`` quantized-cell sets the
-        cell-exact prune stage intersects."""
-        e = self._entry(view)
-        if e is None:
-            return compute()
-        got = e.bitmaps.get((int(block), int(scale)))
-        if got is None:
-            self.misses += 1
-            got = e.bitmaps[(int(block), int(scale))] = compute()
-        else:
-            self.hits += 1
-        return got
-
-    def refined_pairs(self, view_a, view_b, block: int, eps: int,
-                      same: bool,
-                      compute: Callable[[], Tuple[np.ndarray, int]]
-                      ) -> Tuple[np.ndarray, int]:
-        """The ``(refined_pairs, killed)`` bitmap-refined pair list for
-        one task (memoized like :meth:`block_pairs` under a distinct
-        ``"bpair"`` tag, so warm queries skip the bitmap intersection
-        pass; invalidated through exactly the same residency hooks)."""
-        return self._pair_artifact("bpair", view_a, view_b, block, eps,
-                                   same, compute)
-
-    def _pair_artifact(self, tag: str, view_a, view_b, block: int,
-                       eps: int, same: bool,
-                       compute: Callable[[], tuple]) -> tuple:
-        """Shared memoization of per-chunk-pair artifacts (bbox pair
-        lists and bitmap-refined pair lists), registered on both sides'
-        chunks so either chunk's residency event invalidates them."""
+        computed directly when either side is uncacheable), registered
+        on both sides' chunks so either chunk's residency event
+        invalidates it."""
         ka = view_a.key if isinstance(view_a, ChunkView) else None
         kb = view_b.key if isinstance(view_b, ChunkView) else None
         if ka is None or kb is None:
             return compute()
-        key = (tag, ka, kb, int(block), int(eps), bool(same))
+        key = ("pair", ka, kb, int(block), int(eps), bool(same))
         got = self._pairs.get(key)
         if got is None:
             self.misses += 1
@@ -283,7 +237,7 @@ class JoinArtifactCache:
     def has_chunk(self, chunk_id: int) -> bool:
         """Whether any artifact derived from this chunk is still live."""
         return any(
-            (k in self._pairs) if k[0] in ("pair", "bpair")
+            (k in self._pairs) if k[0] == "pair"
             else (k in self._entries)
             for k in self._by_chunk.get(chunk_id, ()))
 
@@ -321,11 +275,10 @@ class JoinArtifactCache:
         dropped = self._entries.pop(old, None) is not None
         keys = self._by_chunk.get(cid, set())
         stale = {k for k in keys
-                 if k == old or (k[0] in ("pair", "bpair")
-                                 and old in (k[1], k[2]))}
+                 if k == old or (k[0] == "pair" and old in (k[1], k[2]))}
         for k in stale:
             keys.discard(k)
-            if k[0] in ("pair", "bpair"):
+            if k[0] == "pair":
                 dropped += self._pairs.pop(k, None) is not None
         self.invalidations += int(dropped)
 
@@ -338,7 +291,7 @@ class JoinArtifactCache:
             return 0
         n = 0
         for k in keys:
-            if k[0] in ("pair", "bpair"):
+            if k[0] == "pair":
                 n += self._pairs.pop(k, None) is not None
             else:
                 n += self._entries.pop(k, None) is not None

@@ -93,15 +93,6 @@ class ExecutedQuery:
     dispatch_max_device_launches: Optional[int] = None
     ship_transfers: Optional[int] = None
     dispatch_backend_compiles: Optional[int] = None
-    # Cell-exact bitmap-prune counters (None unless the bitmap stage ran
-    # on at least one multi-block candidate this query — prune="bitmap",
-    # or "auto" past its single-block fast path — so summaries of
-    # workloads that never engage the feature are bit-identical to the
-    # pre-bitmap ones): block pairs the hierarchical-bitmap intersection
-    # proved dead after surviving the bbox prune, and the refinement
-    # stage's wall-clock (also traced as a ``prep.bitmap`` span).
-    block_pairs_bitmap_killed: Optional[int] = None
-    bitmap_build_s: Optional[float] = None
     # Cross-batch multi-query-optimization counters (None when the
     # backend's ``mqo`` knob is off or the query was served from the
     # result cache): of this query's join tasks, how many there were
@@ -224,7 +215,6 @@ SUMMARY_GROUPS: Dict[str, str] = {
     "block_pairs_total": "block", "block_pairs_evaluated": "block",
     "prep_s": "prep", "dispatch_s": "prep",
     "artifact_hits": "prep", "artifact_misses": "prep",
-    "block_pairs_bitmap_killed": "bitmap", "bitmap_build_s": "bitmap",
     "mqo_tasks_total": "mqo", "mqo_tasks_executed": "mqo",
     "mqo_shared_hits": "mqo",
     "replica_hits": "replica", "replicas_dropped": "replica",
@@ -290,8 +280,6 @@ def record_executed(registry: MetricsRegistry, e: ExecutedQuery) -> None:
     c("dispatch_s").inc(e.dispatch_s or 0.0)
     c("artifact_hits").inc(e.artifact_hits or 0)
     c("artifact_misses").inc(e.artifact_misses or 0)
-    c("block_pairs_bitmap_killed").inc(e.block_pairs_bitmap_killed or 0)
-    c("bitmap_build_s").inc(e.bitmap_build_s or 0.0)
     c("mqo_tasks_total").inc(e.mqo_tasks_total or 0)
     c("mqo_tasks_executed").inc(e.mqo_tasks_executed or 0)
     c("mqo_shared_hits").inc(e.mqo_shared_hits or 0)
@@ -318,8 +306,6 @@ def record_executed(registry: MetricsRegistry, e: ExecutedQuery) -> None:
         registry.mark_group("block")
     if e.prep_s is not None:
         registry.mark_group("prep")
-    if e.block_pairs_bitmap_killed is not None:
-        registry.mark_group("bitmap")
     if e.mqo_tasks_total is not None:
         registry.mark_group("mqo")
     if e.replica_hits is not None:

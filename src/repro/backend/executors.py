@@ -8,26 +8,13 @@
     and shape-bucketed pair batches are dispatched to the
     ``kernels/simjoin`` Pallas kernel (compiled on the chip; interpret
     mode on CPU devices only, per ``simjoin.interpret_mode``). Its
-    ``prune`` knob selects the grid per task:
+    ``prune`` knob selects the grid:
 
-      - ``"dense"`` — every block pair evaluated (the parity reference);
-      - ``"block"`` — coordinates spatially sorted, per-block bounding
-        boxes pruned against ``eps`` on host, only live block pairs
-        scalar-prefetched into the kernel (``kernels.simjoin.prune``);
-      - ``"bitmap"`` — the bbox-pruned pair list is refined by a second,
-        cell-exact stage: hierarchical occupancy bitmaps per block
-        (``prune.build_bitmaps``) are intersected per surviving pair and
-        pairs whose occupied cells are provably > eps apart are killed
-        (``prune.refine_block_pairs``) before the list is padded and
-        scalar-prefetched — strictly fewer live pairs, identical counts;
-      - ``"auto"`` (default) — per task, the block-sparse grid only when
-        it can win: a task goes dense when its *post-bitmap refined*
-        pair list, padded, would be at least as long as the dense grid
-        (``padded_pair_len(refined) >= dense blocks``), which covers
-        single-block chunk pairs (a dense grid of 1 is below the minimum
-        pad of 8) and near-dense pair lists in one rule — the block
-        kernel's cost is proportional to the *padded* pair count, so
-        this choice is never the slower one.
+      - ``"block"`` (default) — coordinates spatially sorted, per-block
+        bounding boxes pruned against ``eps`` on host, only live block
+        pairs scalar-prefetched into the kernel
+        (``kernels.simjoin.prune``);
+      - ``"dense"`` — every block pair evaluated (the parity reference).
 
 Host-side prep (sort, boxes, padding, pair lists) is memoized in a
 :class:`repro.backend.artifacts.JoinArtifactCache` when tasks carry
@@ -39,12 +26,7 @@ Every pallas dispatch records ``last_stats``: ``block_pairs_total`` (the
 dense grid size) and ``block_pairs_evaluated`` (block pairs actually
 dispatched), plus ``prep_s``/``dispatch_s`` wall-clock and the query's
 ``artifact_hits``/``artifact_misses`` — the backends surface all of them
-per query on ``ExecutedQuery``. When the bitmap stage engages (bitmap or
-auto mode with at least one multi-block candidate), stats additionally
-carry ``block_pairs_bitmap_killed`` (pairs the cell-exact stage proved
-dead) and ``bitmap_build_s`` (its wall-clock, also traced as a
-``prep.bitmap`` span); the keys are absent otherwise, so summaries of
-workloads that never engage the feature are unchanged.
+per query on ``ExecutedQuery``.
 """
 from __future__ import annotations
 
@@ -59,7 +41,7 @@ from repro.backend.artifacts import ChunkView, JoinArtifactCache, task_coords
 from repro.obs.trace import NULL_TRACER
 
 JOIN_BACKENDS = ("numpy", "pallas")
-PRUNE_MODES = ("dense", "block", "bitmap", "auto")
+PRUNE_MODES = ("dense", "block")
 
 # One unit of join work: (node, a side, b side, self-join?). Each side is
 # a (n, d) coordinate array or a ChunkView wrapping one (see
@@ -167,19 +149,12 @@ class PallasJoinExecutor:
     mesh backend (``repro.backend.jax_mesh``) keys buckets by node and
     pins each bucket to that node's device.
 
-    ``prune`` selects the grid: ``"dense"`` (full grid — parity
-    reference and fallback), ``"block"`` (always block-sparse: per task
-    the coordinates are spatially sorted, live block pairs computed on
-    host, and the pair list — padded to a power-of-two bucket length so
-    pair-count jitter does not retrace — scalar-prefetched into the
-    kernel), ``"bitmap"`` (block-sparse with the cell-exact second
-    stage: hierarchical occupancy bitmaps kill bbox-surviving pairs
-    whose occupied cells are provably > eps apart before the list is
-    padded), or ``"auto"`` (default: per task, block-sparse only when
-    the padded *bitmap-refined* pair list is shorter than the dense
-    grid — single-block chunk pairs and near-dense pair lists dispatch
-    dense, so auto never pays prefetch overhead the prune cannot
-    recoup).
+    ``prune`` selects the grid: ``"block"`` (default, block-sparse: per
+    task the coordinates are spatially sorted, live block pairs
+    computed on host, and the pair list — padded to a power-of-two
+    bucket length so pair-count jitter does not retrace —
+    scalar-prefetched into the kernel) or ``"dense"`` (full grid — the
+    parity reference).
 
     Host-side prep is memoized in :attr:`artifacts` (a
     :class:`~repro.backend.artifacts.JoinArtifactCache`) for tasks whose
@@ -201,14 +176,12 @@ class PallasJoinExecutor:
     # without re-running is a faithful redo since both are pure.
     fault_points = ("prep.build", "dispatch.kernel")
 
-    def __init__(self, prune: str = "auto",
+    def __init__(self, prune: str = "block",
                  artifacts: Optional[JoinArtifactCache] = None,
                  devices: Optional[Sequence[Any]] = None):
+        check_prune(prune)
         # Imported lazily so the numpy backend never pulls in jax.
         from repro.kernels.simjoin import ops, prune as prune_mod, simjoin
-        if prune not in PRUNE_MODES:
-            raise ValueError(f"unknown prune mode {prune!r}; "
-                             f"known: {PRUNE_MODES}")
         self._ops = ops
         self._prune = prune_mod
         self._block = simjoin.BLOCK
@@ -261,41 +234,13 @@ class PallasJoinExecutor:
             lambda: self._prune.build_block_pairs(
                 a_s, b_s, self._block, int(eps), bool(same)))
 
-    def _bitmaps(self, x, sorted_arr: np.ndarray, scale: int) -> list:
-        """One task side's hierarchical occupancy bitmaps
-        (artifact-cached per block size + quantization scale for
-        ChunkViews, computed in place for raw arrays)."""
-        if isinstance(x, ChunkView) and x.key is not None:
-            return self.artifacts.bitmaps(
-                x, self._block, scale,
-                lambda: self._prune.build_bitmaps(
-                    sorted_arr, self._block, scale))
-        return self._prune.build_bitmaps(sorted_arr, self._block, scale)
-
-    def _refined_pairs(self, xa, xb, a_s: np.ndarray, b_s: np.ndarray,
-                       pairs: np.ndarray, eps: int, same: bool
-                       ) -> Tuple[np.ndarray, int]:
-        """The task's ``(refined_pairs, killed)`` after the cell-exact
-        bitmap stage (artifact-cached like the bbox pair list; warm
-        queries skip both the bitmap build and the intersection pass)."""
-        scale = self._prune.bitmap_scale(eps)
-
-        def compute():
-            bm_a = self._bitmaps(xa, a_s, scale)
-            bm_b = bm_a if same else self._bitmaps(xb, b_s, scale)
-            return self._prune.refine_block_pairs(
-                pairs, bm_a, bm_b, int(eps), scale)
-
-        return self.artifacts.refined_pairs(
-            xa, xb, self._block, int(eps), bool(same), compute)
-
     # ------------------------------------------------- batch preparation
 
     def iter_batches(self, tasks: Sequence[JoinTask], eps: int,
                      by_node: bool = False
                      ) -> Tuple[List[PreparedBatch], Dict[str, int]]:
-        """Bucket and stack the tasks' kernel inputs (dense, block, or
-        per-task auto-selected per the ``prune`` knob); returns
+        """Bucket and stack the tasks' kernel inputs (dense or block
+        grid, per the ``prune`` knob); returns
         ``(batches, stats)`` where stats carries the query's
         ``block_pairs_total`` / ``_evaluated``, the host-side ``prep_s``
         wall-clock, and the artifact-cache hit/miss deltas."""
@@ -305,9 +250,7 @@ class PallasJoinExecutor:
             if self.prune == "dense":
                 batches, stats = self._batches_dense(tasks, by_node)
             else:
-                batches, stats = self._batches_block(
-                    tasks, eps, by_node, auto=self.prune == "auto",
-                    bitmap=self.prune in ("bitmap", "auto"))
+                batches, stats = self._batches_block(tasks, eps, by_node)
         stats["prep_s"] = time.perf_counter() - t0
         stats["artifact_hits"] = self.artifacts.hits - h0
         stats["artifact_misses"] = self.artifacts.misses - m0
@@ -377,38 +320,18 @@ class PallasJoinExecutor:
                          "block_pairs_evaluated": total}
 
     def _batches_block(self, tasks: Sequence[JoinTask], eps: int,
-                       by_node: bool, auto: bool = False,
-                       bitmap: bool = False
+                       by_node: bool
                        ) -> Tuple[List[PreparedBatch], Dict[str, int]]:
-        """Block-sparse grid: sort, prune, and pad each task's pair
-        list; tasks with no surviving block pair skip dispatch (their
-        count is provably zero). With ``bitmap``, bbox-surviving pair
-        lists pass a second, cell-exact refinement stage (hierarchical
-        occupancy bitmaps, ``prune.refine_block_pairs``) before routing
-        — run as a distinct ``prep.bitmap`` phase so its wall-clock and
-        killed-pair counters are attributable. With ``auto``, a task
-        whose padded (refined) pair list cannot beat its dense grid is
-        routed to a dense bucket instead — single-block chunk pairs skip
-        pair-list construction entirely (a dense grid of one block is
-        already minimal)."""
-        total = evaluated = killed = 0
+        """Block-sparse grid: per task, sort both sides, build the live
+        block-pair list, and bucket the task by its padded shapes and
+        pair-list length; tasks with no surviving block pair skip
+        dispatch (their count is provably zero)."""
+        total = evaluated = 0
         prepped: Dict[int, tuple] = {}
-        block_buckets: Dict[tuple, List[int]] = {}
-        dense_buckets: Dict[tuple, List[int]] = {}
-        # Phase 1 — bbox prune: sorted sides + live block-pair lists.
-        cand: List[tuple] = []
+        buckets: Dict[tuple, List[int]] = {}
         for i, (node, a, b, same) in enumerate(tasks):
             ca, cb = task_coords(a), task_coords(b)
             if ca.shape[0] == 0 or cb.shape[0] == 0:
-                continue
-            na = -(-ca.shape[0] // self._block) * self._block
-            nb = -(-cb.shape[0] // self._block) * self._block
-            grid = (na // self._block) * (nb // self._block)
-            dkey = ((node,) if by_node else ()) + (same, na, nb)
-            if auto and grid == 1:
-                total += 1
-                evaluated += 1
-                dense_buckets.setdefault(dkey, []).append(i)
                 continue
             a_s = self._sorted_side(a)
             b_s = a_s if same else self._sorted_side(b)
@@ -416,63 +339,23 @@ class PallasJoinExecutor:
             total += dense_total
             if pairs.shape[0] == 0:
                 continue
-            cand.append((i, dkey, a, b, a_s, b_s, same, pairs, dense_total))
-        # Phase 2 — cell-exact refinement of every bbox survivor (the
-        # stats keys appear iff this stage actually ran on a candidate,
-        # so workloads that never engage it keep seed-shaped stats).
-        bitmap_s = None
-        if bitmap and cand:
-            tb = time.perf_counter()
-            with self.tracer.span("prep.bitmap", candidates=len(cand)):
-                refined_cand = []
-                for (i, dkey, a, b, a_s, b_s, same, pairs,
-                     dense_total) in cand:
-                    pairs, k = self._refined_pairs(
-                        a, b, a_s, b_s, pairs, eps, same)
-                    killed += k
-                    refined_cand.append(
-                        (i, dkey, a_s, b_s, pairs, dense_total))
-                cand = refined_cand
-            bitmap_s = time.perf_counter() - tb
-        else:
-            cand = [(i, dkey, a_s, b_s, pairs, dense_total)
-                    for (i, dkey, a, b, a_s, b_s, same, pairs,
-                         dense_total) in cand]
-        # Phase 3 — routing: fully-killed tasks skip dispatch (their
-        # count is provably zero); auto compares the padded refined
-        # length against the dense grid.
-        for (i, dkey, a_s, b_s, pairs, dense_total) in cand:
-            if pairs.shape[0] == 0:
-                continue
-            if (auto and self._prune.padded_pair_len(pairs.shape[0])
-                    >= dense_total):
-                evaluated += dense_total
-                dense_buckets.setdefault(dkey, []).append(i)
-                continue
             evaluated += pairs.shape[0]
-            plen = self._prune.padded_pair_len(pairs.shape[0])
             prepped[i] = (a_s, b_s, pairs)
-            block_buckets.setdefault(dkey + (plen,), []).append(i)
+            na = -(-ca.shape[0] // self._block) * self._block
+            nb = -(-cb.shape[0] // self._block) * self._block
+            plen = self._prune.padded_pair_len(pairs.shape[0])
+            key = ((node,) if by_node else ()) + (same, na, nb, plen)
+            buckets.setdefault(key, []).append(i)
         batches: List[PreparedBatch] = []
-        for key, idxs in block_buckets.items():
+        for key, idxs in buckets.items():
             node = key[0] if by_node else None
             same, na, nb, plen = key[-4:]
             batches += self._grouped(
                 node, same, idxs, ("block", same, na, nb, plen),
                 functools.partial(self._stack_block, tasks, prepped,
                                   plen=plen))
-        for key, idxs in dense_buckets.items():
-            node = key[0] if by_node else None
-            same, na, nb = key[-3:]
-            batches += self._grouped(
-                node, same, idxs, ("dense", same, na, nb),
-                lambda part: self._stack_dense(tasks, part))
-        stats = {"block_pairs_total": total,
-                 "block_pairs_evaluated": evaluated}
-        if bitmap_s is not None:
-            stats["block_pairs_bitmap_killed"] = killed
-            stats["bitmap_build_s"] = bitmap_s
-        return batches, stats
+        return batches, {"block_pairs_total": total,
+                         "block_pairs_evaluated": evaluated}
 
     # ---------------------------------------------------------- dispatch
 
@@ -513,24 +396,26 @@ class PallasJoinExecutor:
         return counts
 
 
+def check_prune(prune: str) -> None:
+    """Reject a ``prune`` mode outside :data:`PRUNE_MODES`, naming the
+    known ones (a stale mode must fail loudly, never fall back)."""
+    if prune not in PRUNE_MODES:
+        raise ValueError(f"unknown prune mode {prune!r}; "
+                         f"known: {PRUNE_MODES}")
+
+
 def make_join_executor(backend: str, join_fn: Callable[..., int],
-                       prune: str = "auto",
+                       prune: str = "block",
                        artifacts: Optional[JoinArtifactCache] = None,
                        devices: Optional[Sequence[Any]] = None):
     """Build a join executor for ``backend``. ``prune`` selects the
-    pallas grid (``"dense"`` full grid / ``"block"`` block-sparse /
-    ``"bitmap"`` block-sparse + cell-exact refinement / ``"auto"``
-    per-task selection, the default); the numpy executor has no block
-    structure, so it accepts the adaptive default as a no-op but rejects
-    an explicit ``"block"`` or ``"bitmap"`` request it cannot honor.
-    ``devices`` are the devices the pallas executor dispatches to (jax's
-    default devices when ``None``); their platform decides interpret
-    mode."""
+    pallas grid (``"block"`` block-sparse, the default, or ``"dense"``
+    full grid); the numpy executor has no grid and counts the same
+    pairs either way, so it accepts both. ``devices`` are the devices
+    the pallas executor dispatches to (jax's default devices when
+    ``None``); their platform decides interpret mode."""
     if backend == "numpy":
-        if prune in ("block", "bitmap"):
-            raise ValueError(
-                f"prune={prune!r} requires the pallas join backend; the "
-                f"numpy executor has no block grid to prune")
+        check_prune(prune)
         return NumpyJoinExecutor(join_fn)
     if backend == "pallas":
         return PallasJoinExecutor(prune=prune, artifacts=artifacts,

@@ -122,7 +122,7 @@ class JaxMeshBackend(SimulatedBackend):
 
     def __init__(self, n_nodes: int, cost_model: Optional[CostModel] = None,
                  devices: Optional[Sequence[Any]] = None,
-                 execute_joins: bool = True, prune: str = "auto",
+                 execute_joins: bool = True, prune: str = "block",
                  mqo: str = "off"):
         import jax
         from jax.sharding import Mesh
@@ -696,8 +696,6 @@ class JaxMeshBackend(SimulatedBackend):
             dispatch_s=stats.get("dispatch_s"),
             artifact_hits=stats.get("artifact_hits"),
             artifact_misses=stats.get("artifact_misses"),
-            block_pairs_bitmap_killed=stats.get("block_pairs_bitmap_killed"),
-            bitmap_build_s=stats.get("bitmap_build_s"),
             dispatch_launches=stats.get("dispatch_launches", 0),
             dispatch_pinned_hits=stats.get("dispatch_pinned_hits", 0),
             dispatch_put_bytes=stats.get("dispatch_put_bytes", 0),
@@ -715,14 +713,12 @@ def make_backend(backend: str, n_nodes: int,
                  join_fn: Optional[Callable[..., int]] = None,
                  join_backend: str = "numpy", execute_joins: bool = True,
                  devices: Optional[Sequence[Any]] = None,
-                 prune: str = "auto", mqo: str = "off") -> SimulatedBackend:
+                 prune: str = "block", mqo: str = "off") -> SimulatedBackend:
     """Build an execution backend by name. ``prune`` selects the Pallas
-    join grid (``"dense"`` / ``"block"``-sparse / ``"bitmap"``
-    block-sparse + cell-exact hierarchical-bitmap refinement /
-    ``"auto"`` per-task selection on post-bitmap refined pair counts,
-    the default) and applies to any backend that joins through the
-    Pallas kernel; ``mqo`` toggles cross-batch task dedup in
-    ``execute_batch`` (off = seed parity). ``devices`` are the mesh's
+    join grid (``"block"``-sparse, the default, or ``"dense"``) and
+    applies to any backend that joins through the Pallas kernel;
+    ``mqo`` toggles cross-batch task dedup in ``execute_batch``
+    (off = seed parity). ``devices`` are the mesh's
     devices for ``jax_mesh`` (all of jax's devices when ``None``); the
     simulated backend runs on jax's default devices."""
     if backend == "simulated":

@@ -47,7 +47,7 @@ class SimulatedBackend:
     def __init__(self, n_nodes: int, cost_model: Optional[CostModel] = None,
                  join_fn: Optional[Callable[..., int]] = None,
                  join_backend: str = "numpy", execute_joins: bool = True,
-                 prune: str = "auto", mqo: str = "off",
+                 prune: str = "block", mqo: str = "off",
                  devices: Optional[Sequence[Any]] = None):
         if mqo not in MQO_MODES:
             raise ValueError(f"unknown mqo mode {mqo!r}; "
@@ -510,8 +510,6 @@ class SimulatedBackend:
             dispatch_s=stats.get("dispatch_s"),
             artifact_hits=stats.get("artifact_hits"),
             artifact_misses=stats.get("artifact_misses"),
-            block_pairs_bitmap_killed=stats.get("block_pairs_bitmap_killed"),
-            bitmap_build_s=stats.get("bitmap_build_s"),
             **self._resilience_fields(report),
             **self._fault_fields(degraded)))
 
@@ -653,9 +651,6 @@ class SimulatedBackend:
                 dispatch_s=stats.get("dispatch_s"),
                 artifact_hits=stats.get("artifact_hits"),
                 artifact_misses=stats.get("artifact_misses"),
-                block_pairs_bitmap_killed=stats.get(
-                    "block_pairs_bitmap_killed"),
-                bitmap_build_s=stats.get("bitmap_build_s"),
                 dispatch_launches=stats.get("dispatch_launches"),
                 dispatch_pinned_hits=stats.get("dispatch_pinned_hits"),
                 dispatch_put_bytes=stats.get("dispatch_put_bytes"),

@@ -27,19 +27,12 @@ Join execution backends for the simulated path (``join_backend``):
   * ``"pallas"`` — the batched executor: BLOCK-padded, shape-bucketed
     pair batches dispatched to the ``kernels/simjoin`` Pallas kernel
     (compiled on the chip; interpret mode on CPU devices only). Its
-    ``prune`` knob selects the grid per task: ``"dense"`` (every block
-    pair evaluated), ``"block"`` (spatially sorted
-    coordinates, host-pruned block pairs scalar-prefetched into the
-    kernel), ``"bitmap"`` (block-sparse plus a cell-exact second stage
-    — hierarchical occupancy bitmaps kill bbox-surviving pairs whose
-    occupied cells are provably > eps apart), or ``"auto"`` (default —
-    block-sparse only where the padded bitmap-refined pair list is
-    shorter than the dense grid, so single-block and near-dense chunk
-    pairs skip prune overhead). Match counts are identical across all
-    four; the work done is reported per query as
-    ``ExecutedQuery.block_pairs_evaluated / block_pairs_total`` (plus
-    ``block_pairs_bitmap_killed``/``bitmap_build_s`` when the bitmap
-    stage engaged).
+    ``prune`` knob selects the grid: ``"block"`` (default — spatially
+    sorted coordinates, host-pruned block pairs scalar-prefetched into
+    the kernel) or ``"dense"`` (every block pair evaluated, the parity
+    reference); any other mode raises ``ValueError``. Match counts are
+    identical across both; the work done is reported per query as
+    ``ExecutedQuery.block_pairs_evaluated / block_pairs_total``.
     Host-side prep (sort/boxes/padding/pair lists) is memoized per
     resident chunk in a ``JoinArtifactCache`` invalidated with cache
     residency; the per-query ``prep_s``/``dispatch_s`` split and
@@ -91,7 +84,7 @@ class RawArrayCluster:
                  reuse: str = "off",
                  backend: str = "simulated",
                  devices: Optional[Sequence[Any]] = None,
-                 prune: str = "auto",
+                 prune: str = "block",
                  mqo: str = "off",
                  result_cache: str = "off",
                  result_cache_capacity: int = 256,

@@ -6,8 +6,7 @@ by the host-side ``repro.kernels.simjoin.prune`` preprocessing.
 ``TRACE_COUNTS`` tallies how often each jitted entry point is *traced*
 (the counter bumps run at trace time only): repeated same-shape
 dispatches must not grow it — the no-recompile guarantee
-``tests/test_simjoin_pruning.py`` asserts and ``BENCH_kernels.json``
-records."""
+``tests/test_simjoin_pruning.py`` asserts."""
 from __future__ import annotations
 
 import collections

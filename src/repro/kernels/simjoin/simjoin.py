@@ -28,11 +28,7 @@ Two kernel variants share the block-pair body:
     instead of O(all block pairs) work. SMEM bounds one call's list at
     :data:`PAIR_CAP` rows; the host splits longer lists into
     ``PAIR_CAP``-row calls, run under ``lax.map``, whose counts are
-    summed. The cell-exact
-    bitmap stage (``prune.refine_block_pairs``) rides this same
-    scalar-prefetch path: it only shrinks the host-built pair list
-    further, so the kernel is untouched and iterates strictly fewer
-    live pairs.
+    summed.
 
 Pallas interpret mode serves CPU devices only; :func:`interpret_mode` is
 the one place that decides it, from the platform of the devices a caller

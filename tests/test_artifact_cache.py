@@ -1,9 +1,10 @@
-"""Join-artifact caching and adaptive prune selection: warm results must
-be bit-identical to cold across evict -> re-admit -> split, artifacts
-must be invalidated on every residency event (``on_drop``/``on_split``/
-``reconcile`` — no stale-artifact path survives), and ``prune="auto"``
-must count exactly what ``"dense"`` and ``"block"`` count on random and
-clustered workloads under both execution backends."""
+"""Join-artifact caching and the prune routes: warm results must be
+bit-identical to cold across evict -> re-admit -> split, artifacts must
+be invalidated on every residency event (``on_drop``/``on_split``/
+``reconcile`` — no stale-artifact path survives), and the served
+``prune="block"`` route must count exactly what the ``"dense"``
+reference and the numpy executor count on random and clustered
+workloads under both execution backends."""
 import tempfile
 
 import numpy as np
@@ -74,45 +75,6 @@ def test_getters_memoize_and_count():
     raw = np.zeros((3, 2), np.int32)
     h, m = cache.hits, cache.misses
     assert cache.block_pairs(v, raw, 128, 3, False, lambda: pairs) is pairs
-    assert (cache.hits, cache.misses) == (h, m)
-
-
-def test_bitmap_getters_memoize_and_invalidate():
-    """The bitmap sidecars and refined pair lists are content-addressed
-    join artifacts: memoized per (block, scale) / chunk pair, distinct
-    from the bbox pair list under the same coordinates, recomputed after
-    either side's chunk leaves residency, and uncached for raw-array
-    sides."""
-    cache = JoinArtifactCache()
-    coords = np.arange(12, dtype=np.int32).reshape(6, 2)
-    q = Box((0, 0), (99, 99))
-    v = cache.view(1, Box((0, 0), (11, 11)), q, coords)
-    w = cache.view(2, Box((50, 50), (60, 60)), q, coords)
-    bm = [(np.zeros((1, 2), np.int64), np.zeros((1, 2), np.int64))]
-    assert cache.bitmaps(v, 128, 8, lambda: bm) is bm
-    assert cache.bitmaps(v, 128, 8, lambda: 0 / 0) is bm      # memoized
-    assert cache.bitmaps(v, 128, 4, lambda: list(bm)) is not bm  # per scale
-    ref = (np.ones((2, 3), np.int32), 1)
-    assert cache.refined_pairs(v, w, 128, 3, False, lambda: ref) is ref
-    assert cache.refined_pairs(v, w, 128, 3, False, lambda: 0 / 0) is ref
-    # The bbox pair list at the same (pair, block, eps, same) key
-    # coordinates is a DIFFERENT artifact (distinct tag).
-    bbox = (np.ones((3, 3), np.int32), 4)
-    assert cache.block_pairs(v, w, 128, 3, False, lambda: bbox) is bbox
-    assert cache.refined_pairs(v, w, 128, 3, False, lambda: 0 / 0) is ref
-    # Dropping either side's chunk invalidates the refined list too.
-    cache.on_drop(2)
-    assert not cache.has_chunk(2)
-    ref2 = (np.zeros((1, 3), np.int32), 2)
-    assert cache.refined_pairs(v, w, 128, 3, False, lambda: ref2) is ref2
-    # on_split retires the bitmap sidecars with the parent id.
-    cache.on_split(1, leaves=[])
-    bm2 = [(np.ones((1, 2), np.int64), np.ones((1, 2), np.int64))]
-    assert cache.bitmaps(v, 128, 8, lambda: bm2) is bm2
-    # Uncacheable side -> computed every time, no counters.
-    raw = np.zeros((3, 2), np.int32)
-    h, m = cache.hits, cache.misses
-    assert cache.refined_pairs(v, raw, 128, 3, False, lambda: ref) is ref
     assert (cache.hits, cache.misses) == (h, m)
 
 
@@ -191,75 +153,27 @@ def uniform_coords(rng, n, d=3, hi=400):
 
 @pytest.mark.parametrize("maker", [clustered_coords, uniform_coords])
 def test_auto_parity_and_counters(maker):
-    """prune="auto" counts exactly what dense/block/bitmap/numpy count,
-    its dense-grid denominator matches theirs, and its evaluated work
-    sits between bitmap's (the tightest prune — auto's block-routed
-    tasks carry the same refined lists, dense-routed ones their full
-    grid) and dense's (upper bound)."""
+    """The block route counts exactly what the dense reference and the
+    numpy executor count, shares the dense grid's denominator, and
+    evaluates no more block pairs than that grid holds."""
     rng = np.random.default_rng(11)
     tasks = make_tasks(rng, maker=maker)
     eps = 40
     dense = PallasJoinExecutor(prune="dense")
     block = PallasJoinExecutor(prune="block")
-    bitmap = PallasJoinExecutor(prune="bitmap")
-    auto = PallasJoinExecutor(prune="auto")
     ref = NumpyJoinExecutor(count_similar_pairs_np)
     cd = dense.count_pairs(tasks, eps)
     cb = block.count_pairs(tasks, eps)
-    cm = bitmap.count_pairs(tasks, eps)
-    ca = auto.count_pairs(tasks, eps)
     cn = ref.count_pairs(tasks, eps)
-    assert cd == cb == cm == ca == cn
-    assert sum(ca) > 0
+    assert cd == cb == cn
+    assert sum(cb) > 0
     t = dense.last_stats["block_pairs_total"]
-    assert auto.last_stats["block_pairs_total"] == t
+    assert dense.last_stats["block_pairs_evaluated"] == t
     assert block.last_stats["block_pairs_total"] == t
-    assert bitmap.last_stats["block_pairs_total"] == t
-    assert (bitmap.last_stats["block_pairs_evaluated"]
-            <= block.last_stats["block_pairs_evaluated"] <= t)
-    assert (bitmap.last_stats["block_pairs_evaluated"]
-            <= auto.last_stats["block_pairs_evaluated"] <= t)
-    assert bitmap.last_stats["block_pairs_bitmap_killed"] >= 0
-    assert bitmap.last_stats["bitmap_build_s"] >= 0
-    for ex in (dense, block, bitmap, auto):
+    assert block.last_stats["block_pairs_evaluated"] <= t
+    for ex in (dense, block):
         assert ex.last_stats["prep_s"] >= 0
         assert ex.last_stats["dispatch_s"] >= 0
-
-
-def test_auto_single_block_tasks_skip_pair_lists():
-    """Single-block chunk pairs go dense without building a pair list:
-    the pruning denominator is the grid size and nothing is pruned."""
-    rng = np.random.default_rng(3)
-    tasks = [(0, clustered_coords(rng, 100), clustered_coords(rng, 90),
-              False)]
-    auto = PallasJoinExecutor(prune="auto")
-    batches, stats = auto.iter_batches(tasks, 10)
-    assert [b.fn_key[0] for b in batches] == ["dense"]
-    assert stats == {"block_pairs_total": 1, "block_pairs_evaluated": 1,
-                     "prep_s": stats["prep_s"],
-                     "artifact_hits": 0, "artifact_misses": 0}
-
-
-def test_auto_routes_near_dense_to_dense_and_sparse_to_block():
-    rng = np.random.default_rng(5)
-    # Tight multi-block cross-join: every block pair survives the eps
-    # prune, so the padded pair list is at least the dense grid -> auto
-    # must pick the dense grid (no prefetch overhead to recoup).
-    near_a = rng.integers(0, 10, size=(600, 3)).astype(np.int32)
-    near_b = rng.integers(0, 10, size=(500, 3)).astype(np.int32)
-    # Widely clustered: most block pairs pruned -> block-sparse grid.
-    sparse = clustered_coords(rng, 4096, n_clusters=12, domain=100_000)
-    auto = PallasJoinExecutor(prune="auto")
-    b1, s1 = auto.iter_batches([(0, near_a, near_b, False)], 30)
-    assert {b.fn_key[0] for b in b1} == {"dense"}
-    assert s1["block_pairs_evaluated"] == s1["block_pairs_total"]
-    # A dense self-join still routes to block: the i <= j pair list is
-    # roughly half the full grid the dense kernel would sweep.
-    b1s, _ = auto.iter_batches([(0, near_a, near_a, True)], 30)
-    assert {b.fn_key[0] for b in b1s} == {"block"}
-    b2, s2 = auto.iter_batches([(0, sparse, sparse, True)], 64)
-    assert {b.fn_key[0] for b in b2} == {"block"}
-    assert s2["block_pairs_evaluated"] < s2["block_pairs_total"] // 2
 
 
 def test_executor_artifact_reuse_with_views():
@@ -270,7 +184,7 @@ def test_executor_artifact_reuse_with_views():
     a = clustered_coords(rng, 900)
     b = clustered_coords(rng, 500)
     q = Box((0, 0, 0), tuple([60_000] * 3))
-    for mode in ("dense", "block", "bitmap", "auto"):
+    for mode in ("dense", "block"):
         ex = PallasJoinExecutor(prune=mode)
         va = ex.artifacts.view(1, Box((0, 0, 0), (50_100, 50_100, 50_100)),
                                q, a)
@@ -288,10 +202,9 @@ def test_executor_artifact_reuse_with_views():
 
 
 def test_bitmap_eps0_and_duplicate_parity():
-    """The eps=0 edge of the cell-exact stage: the quantization step
-    degenerates to 1 and the occupied-cell test is an exact point
-    membership test — duplicated cells (the only eps=0 matches) must
-    count identically under every prune mode."""
+    """The eps=0 edge: duplicated cells are the only matches, and the
+    box prune's distance bound is exact there — they must count
+    identically under both prune routes and the numpy reference."""
     rng = np.random.default_rng(17)
     base = clustered_coords(rng, 600)
     dup = np.repeat(base[:40], 10, axis=0)        # duplicates: matches
@@ -301,42 +214,23 @@ def test_bitmap_eps0_and_duplicate_parity():
         want = NumpyJoinExecutor(count_similar_pairs_np).count_pairs(
             tasks, eps)
         assert sum(want) > 0
-        for mode in ("dense", "block", "bitmap", "auto"):
+        for mode in ("dense", "block"):
             got = PallasJoinExecutor(prune=mode).count_pairs(tasks, eps)
             assert got == want, (mode, eps)
 
 
-def test_bitmap_stats_only_when_engaged():
-    """The bitmap counters ride a conditional emission group: present
-    exactly when the refinement stage ran on >= 1 multi-block candidate
-    — absent under dense/block modes and on auto's single-block fast
-    path, so summaries of workloads that never engage the feature are
-    bit-identical to the pre-bitmap ones."""
-    rng = np.random.default_rng(23)
-    multi = clustered_coords(rng, 600)
-    ex = PallasJoinExecutor(prune="bitmap")
-    ex.count_pairs([(0, multi, multi, True)], 40)
-    assert ex.last_stats["block_pairs_bitmap_killed"] >= 0
-    assert ex.last_stats["bitmap_build_s"] >= 0
-    blk = PallasJoinExecutor(prune="block")
-    blk.count_pairs([(0, multi, multi, True)], 40)
-    assert "block_pairs_bitmap_killed" not in blk.last_stats
-    small = clustered_coords(rng, 100)            # single 128-block
-    au = PallasJoinExecutor(prune="auto")
-    au.count_pairs([(0, small, small, True)], 40)
-    assert "block_pairs_bitmap_killed" not in au.last_stats
-
-
 def test_auto_default_is_accepted_by_every_executor():
-    """``"auto"`` is the safe default everywhere: the numpy executor
-    (no block structure) accepts it as a no-op, the pallas executor
-    adopts it as its default prune mode (explicit ``"block"`` rejection
-    stays covered in test_simjoin_pruning)."""
+    """``"block"`` is the default everywhere: the numpy executor (no
+    grid, the same pairs either way) accepts it, and the pallas
+    executor serves it (stale modes are rejected in
+    test_simjoin_pruning)."""
     assert isinstance(make_join_executor("numpy", count_similar_pairs_np),
                       NumpyJoinExecutor)
     assert isinstance(make_join_executor(
-        "numpy", count_similar_pairs_np, prune="auto"), NumpyJoinExecutor)
-    assert PallasJoinExecutor().prune == "auto"
+        "numpy", count_similar_pairs_np, prune="block"), NumpyJoinExecutor)
+    assert PallasJoinExecutor().prune == "block"
+    assert make_join_executor("pallas", count_similar_pairs_np).prune \
+        == "block"
 
 
 # ------------------------------------------------- cluster-level parity
@@ -352,7 +246,7 @@ def dataset():
     return catalog, data
 
 
-def make_cluster(dataset, backend="simulated", prune="auto",
+def make_cluster(dataset, backend="simulated", prune="block",
                  budget_frac=8, min_cells=512):
     from repro.arrayio.catalog import FileReader
     from repro.core.cluster import RawArrayCluster
@@ -372,16 +266,14 @@ def workload(catalog, eps=400):
 
 @pytest.mark.parametrize("backend", ["simulated", "jax_mesh"])
 def test_prune_mode_parity_both_backends(dataset, backend):
-    """Match counts bit-identical across prune=dense|block|bitmap|auto
-    on each backend (the ISSUE-5 acceptance gate, extended to the
-    cell-exact bitmap stage by ISSUE 9)."""
+    """Match counts bit-identical across prune=dense|block on each
+    backend."""
     catalog, _ = dataset
     queries = workload(catalog)
     runs = {p: [e.matches for e in
                 make_cluster(dataset, backend, p).run_workload(queries)]
-            for p in ("dense", "block", "bitmap", "auto")}
-    assert (runs["dense"] == runs["block"] == runs["bitmap"]
-            == runs["auto"])
+            for p in ("dense", "block")}
+    assert runs["dense"] == runs["block"]
     assert sum(m or 0 for m in runs["dense"]) > 0
 
 
@@ -405,13 +297,12 @@ def test_warm_equals_cold_with_hits(dataset):
             assert e.prep_s is not None and e.dispatch_s is not None
 
 
-@pytest.mark.parametrize("prune_mode", ["auto", "bitmap"])
-def test_warm_bit_identical_across_evict_readmit_split(dataset,
-                                                       prune_mode):
+@pytest.mark.parametrize("backend", ["simulated", "jax_mesh"])
+def test_warm_bit_identical_across_evict_readmit_split(dataset, backend):
     """The acceptance sequence: evict -> re-admit -> split, every step
-    answered identically by a long-lived (warm) cluster, a fresh dense
-    cluster, and the numpy reference — no stale-artifact path, including
-    the bitmap sidecars and refined pair lists of prune="bitmap"."""
+    answered identically by a long-lived (warm) prune="block" cluster on
+    each backend, a fresh dense cluster, and the numpy reference — no
+    stale-artifact (or, on the mesh, stale pinned-stack) path."""
     from repro.arrayio.catalog import FileReader
     from repro.core.cluster import RawArrayCluster
     from repro.core.coordinator import SimilarityJoinQuery
@@ -422,7 +313,7 @@ def test_warm_bit_identical_across_evict_readmit_split(dataset,
     mid = tuple((l + h) // 2 for l, h in zip(d.lo, d.hi))
     q_sub = SimilarityJoinQuery(box=Box(d.lo, mid), eps=400)
     seq = q_main + q_main + [q_sub] + q_main     # repeat / split / repeat
-    warm = make_cluster(dataset, prune=prune_mode,
+    warm = make_cluster(dataset, backend, prune="block",
                         budget_frac=16,             # tight: forces evicts
                         min_cells=256)
     got = [e.matches for e in warm.run_workload(seq)]
@@ -497,21 +388,3 @@ def test_workload_summary_amortization_counters(dataset):
                              policy="cost", min_cells=512,
                              join_backend="numpy").run_workload(queries)
     assert "prep_s" not in workload_summary(np_run)
-
-
-def test_workload_summary_bitmap_group_gating(dataset):
-    """``block_pairs_bitmap_killed``/``bitmap_build_s`` surface in
-    ``workload_summary`` exactly when the cell-exact stage engaged:
-    present under prune="bitmap", absent under prune="block" (whose
-    summaries must stay bit-identical to the pre-bitmap seed shape)."""
-    from repro.core.cluster import workload_summary
-    catalog, _ = dataset
-    queries = workload(catalog)[:3]
-    with_bitmap = workload_summary(
-        make_cluster(dataset, prune="bitmap").run_workload(queries))
-    assert "block_pairs_bitmap_killed" in with_bitmap
-    assert with_bitmap["bitmap_build_s"] >= 0
-    without = workload_summary(
-        make_cluster(dataset, prune="block").run_workload(queries))
-    assert "block_pairs_bitmap_killed" not in without
-    assert "bitmap_build_s" not in without

@@ -311,40 +311,6 @@ def test_min_l1_box_dist_lower_bounds_cell_distance(pts_a, pts_b, block):
             assert dmat[i // block, j // block] <= cell_dist
 
 
-@given(st.lists(st.tuples(st.integers(-300, 300), st.integers(-300, 300)),
-                min_size=1, max_size=80),
-       st.lists(st.tuples(st.integers(-300, 300), st.integers(-300, 300)),
-                min_size=1, max_size=80),
-       st.integers(1, 16), st.integers(0, 120))
-@settings(max_examples=60, deadline=None)
-def test_bitmap_refine_never_kills_a_matching_pair(pts_a, pts_b, block,
-                                                   eps):
-    """Soundness of the cell-exact bitmap stage (the superset-of-matches
-    invariant): ``refine_block_pairs`` never kills a block pair that
-    contains a true match — every cell pair within eps lives in a block
-    pair that survives BOTH prune stages. Exercises negative
-    coordinates (floor-division quantization), the eps=0 exact edge,
-    and arbitrary block sizes. (Pure numpy: the prune module never
-    imports jax.)"""
-    from repro.kernels.simjoin.prune import (bitmap_scale, build_bitmaps,
-                                             build_block_pairs,
-                                             refine_block_pairs,
-                                             spatial_sort)
-    a = spatial_sort(np.asarray(pts_a, dtype=np.int64))
-    b = spatial_sort(np.asarray(pts_b, dtype=np.int64))
-    pairs, _ = build_block_pairs(a, b, block, eps, False)
-    scale = bitmap_scale(eps)
-    bm_a = build_bitmaps(a, block, scale)
-    bm_b = build_bitmaps(b, block, scale)
-    refined, killed = refine_block_pairs(pairs, bm_a, bm_b, eps, scale)
-    assert killed == pairs.shape[0] - refined.shape[0]
-    live = {(int(i), int(j)) for i, j, _ in refined}
-    for i in range(a.shape[0]):
-        for j in range(b.shape[0]):
-            if int(np.abs(a[i] - b[j]).sum()) <= eps:
-                assert (i // block, j // block) in live
-
-
 @given(st.lists(st.tuples(st.integers(0, 50), st.integers(0, 6),
                           st.integers(0, 6)), min_size=2, max_size=80))
 @settings(max_examples=50, deadline=None)

@@ -97,8 +97,7 @@ def test_replication_off_is_single_copy_seed_path(ptf, backend):
         s = workload_summary(executed)
         return {k: v for k, v in s.items()
                 if k not in ("total_time_s", "opt_time_s", "prep_s",
-                             "dispatch_s", "bitmap_build_s",
-                             "measured_net_s", "recovery_s")}
+                             "dispatch_s", "measured_net_s", "recovery_s")}
     assert modeled(ed) == modeled(ee)
     summary = workload_summary(ee)
     assert "replica_hits" not in summary

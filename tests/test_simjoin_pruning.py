@@ -177,7 +177,7 @@ def test_no_retrace_across_repeated_same_shape_queries():
     only) — the recompile guard of the batched executor."""
     rng = np.random.default_rng(2)
     tasks = make_tasks(rng, k=4)
-    for prune_mode in ("dense", "block", "bitmap"):
+    for prune_mode in ("dense", "block"):
         ex = PallasJoinExecutor(prune=prune_mode)
         first = ex.count_pairs(tasks, 25)       # traces once per bucket
         before = dict(ops.TRACE_COUNTS)
@@ -187,13 +187,22 @@ def test_no_retrace_across_repeated_same_shape_queries():
         assert len(ex._fn_cache) > 0
 
 
-def test_make_join_executor_prune_validation():
-    with pytest.raises(ValueError, match="prune"):
-        make_join_executor("numpy", count_similar_pairs_np, prune="block")
-    with pytest.raises(ValueError, match="prune"):
-        make_join_executor("numpy", count_similar_pairs_np, prune="bitmap")
-    with pytest.raises(ValueError, match="unknown prune mode"):
-        PallasJoinExecutor(prune="sparse")
+@pytest.mark.parametrize("mode", ["bitmap", "auto", "sparse"])
+@pytest.mark.parametrize("join_backend", ["numpy", "pallas"])
+def test_make_join_executor_prune_validation(join_backend, mode):
+    """A prune mode outside ("dense", "block") — the retired bitmap and
+    auto modes included — fails loudly, naming the known modes, at the
+    executor factory and at the front door; it never falls back."""
+    from repro.core.cluster import RawArrayCluster
+    known = r"known: \('dense', 'block'\)"
+    with pytest.raises(ValueError, match=known):
+        make_join_executor(join_backend, count_similar_pairs_np, prune=mode)
+    with pytest.raises(ValueError, match=known):
+        RawArrayCluster(None, None, 4, 1_000, join_backend=join_backend,
+                        prune=mode)
+    if join_backend == "pallas":
+        with pytest.raises(ValueError, match="unknown prune mode"):
+            PallasJoinExecutor(prune=mode)
 
 
 # ------------------------------------------------------ backend parity

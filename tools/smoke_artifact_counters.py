@@ -2,7 +2,7 @@
 """CI smoke gate for the join-artifact cache (ISSUE 5 satellite).
 
 Runs a repeat-query clustered workload through the default
-(``prune="auto"``, pallas) cluster and fails unless the warm pass
+(``prune="block"``, pallas) cluster and fails unless the warm pass
 
   * reports ``artifact_hits > 0`` — catches a silent cache bypass where
     the counters are wired but the executors stopped consulting the
