@@ -20,7 +20,11 @@ Host-side prep (sort, boxes, padding, pair lists) is memoized in a
 :class:`repro.backend.artifacts.JoinArtifactCache` when tasks carry
 :class:`~repro.backend.artifacts.ChunkView` handles (attached by the
 backends, invalidated with cache residency); bare ndarray tasks prep
-uncached, preserving the seed behavior for direct callers.
+uncached, preserving the seed behavior for direct callers. Prep runs in
+two steps: grouping (:meth:`PallasJoinExecutor.iter_batches`) decides
+every batch without building its arrays, and stacking
+(:meth:`PallasJoinExecutor.stack`) builds the arrays of only the
+batches that will be placed on a device.
 
 Every pallas dispatch records ``last_stats``: ``block_pairs_total`` (the
 dense grid size) and ``block_pairs_evaluated`` (block pairs actually
@@ -51,22 +55,29 @@ JoinTask = Tuple[int, np.ndarray, np.ndarray, bool]
 
 @dataclasses.dataclass
 class PreparedBatch:
-    """One group of a shape bucket's stacked kernel inputs, ready for
-    dispatch.
+    """One group of a shape bucket's tasks, decided for dispatch but
+    stacked only on demand.
 
-    ``arrays`` is ``(a_stack, b_stack)`` for the dense grid or
-    ``(a_stack, b_stack, pairs_stack)`` for the block-sparse grid, each
-    ``ops.GROUP`` deep: the tasks ``idxs``, then inert padding;
-    ``fn_key`` identifies the jitted entry point + static shape bucket
-    (the executor memoizes the bound callable per ``fn_key`` + eps).
-    The mesh backend re-places ``arrays`` onto ``node``'s device before
-    dispatch; ``node`` is ``None`` for node-agnostic bucketing."""
+    ``idxs`` are the group's tasks (at most ``ops.GROUP``); ``fn_key``
+    identifies the jitted entry point + static shape bucket (the
+    executor memoizes the bound callable per ``fn_key`` + eps); ``node``
+    is the executing node, ``None`` for node-agnostic bucketing.
+    ``rows(i)`` gives task ``i``'s kernel inputs: its padded a side, b
+    side and, on the block-sparse grid, its packed pair list.
+
+    ``arrays`` stays ``None`` until :meth:`PallasJoinExecutor.stack`
+    writes those rows into ``(a_stack, b_stack)`` (dense) or
+    ``(a_stack, b_stack, pairs_stack)`` (block-sparse), each
+    ``ops.GROUP`` deep: the tasks ``idxs``, then inert padding. The mesh
+    backend stacks only the batches it does not find among its pinned
+    device stacks, and places those onto ``node``'s device."""
 
     node: Optional[int]
     same: bool
     idxs: List[int]
-    arrays: Tuple[np.ndarray, ...]
     fn_key: tuple
+    rows: Callable[[int], Tuple[np.ndarray, ...]]
+    arrays: Optional[Tuple[np.ndarray, ...]] = None
 
 
 def count_similar_pairs_np(a: np.ndarray, b: np.ndarray, eps: int,
@@ -195,8 +206,8 @@ class PallasJoinExecutor:
         self._fn_cache: Dict[tuple, Callable] = {}
         self.last_stats: Optional[Dict[str, int]] = None
         # Backends swap in a live tracer at bind time (telemetry on);
-        # prep/dispatch spans bracket the host-side batch build and the
-        # kernel-dispatch loop respectively.
+        # prep/prep.stack/dispatch spans bracket the host-side grouping,
+        # the stacking and the kernel-dispatch loop respectively.
         self.tracer = NULL_TRACER
 
     # ------------------------------------------------ artifact-aware prep
@@ -239,11 +250,15 @@ class PallasJoinExecutor:
     def iter_batches(self, tasks: Sequence[JoinTask], eps: int,
                      by_node: bool = False
                      ) -> Tuple[List[PreparedBatch], Dict[str, int]]:
-        """Bucket and stack the tasks' kernel inputs (dense or block
-        grid, per the ``prune`` knob); returns
+        """Group the tasks into batches (dense or block grid, per the
+        ``prune`` knob) without stacking them: per task the artifact
+        lookups, bucket key and block-pair counters, then each bucket
+        cut into batches of at most ``ops.GROUP`` tasks whose
+        ``arrays`` are still ``None`` (see :meth:`stack`). Returns
         ``(batches, stats)`` where stats carries the query's
         ``block_pairs_total`` / ``_evaluated``, the host-side ``prep_s``
-        wall-clock, and the artifact-cache hit/miss deltas."""
+        wall-clock of the grouping, and its artifact-cache hit/miss
+        deltas; :meth:`stack` adds its own to the last three."""
         t0 = time.perf_counter()
         h0, m0 = self.artifacts.hits, self.artifacts.misses
         with self.tracer.span("prep", tasks=len(tasks)):
@@ -256,52 +271,72 @@ class PallasJoinExecutor:
         stats["artifact_misses"] = self.artifacts.misses - m0
         return batches, stats
 
-    def _stack_dense(self, tasks: Sequence[JoinTask], idxs: Sequence[int]
-                     ) -> Tuple[np.ndarray, np.ndarray]:
-        """Pad one dense bucket's coordinate sets to BLOCK (±sentinel
-        fill) and stack them into the (k, d, N) batches the batched
-        kernel consumes."""
-        a_stack = np.stack([self._padded_side(tasks[i][1], self._sentinel)
-                            for i in idxs])
-        b_stack = np.stack([self._padded_side(tasks[i][2], -self._sentinel)
-                            for i in idxs])
-        return a_stack, b_stack
+    def stack(self, batches: Sequence[PreparedBatch],
+              stats: Dict[str, Any]) -> float:
+        """Build the host arrays of ``batches`` in a ``prep.stack``
+        span: each operand is written row by row into one preallocated
+        ``ops.GROUP``-deep array, and the rows after the batch's tasks
+        are filled with inert values (sentinel coordinates, an
+        all-invalid pair list) that the kernel loop never reaches.
+        Adds the wall-clock and the artifact hits/misses of the padded
+        sides it looks up to ``stats``' ``prep_s``, ``artifact_hits``
+        and ``artifact_misses``; returns the seconds spent."""
+        if not batches:
+            return 0.0
+        t0 = time.perf_counter()
+        h0, m0 = self.artifacts.hits, self.artifacts.misses
+        fills = (self._sentinel, -self._sentinel, 0)
+        depth = self._ops.GROUP
+        with self.tracer.span("prep.stack", batches=len(batches)):
+            for batch in batches:
+                out: Tuple[np.ndarray, ...] = ()
+                for k, i in enumerate(batch.idxs):
+                    rows = batch.rows(i)
+                    if not out:
+                        out = tuple(np.empty((depth,) + r.shape, r.dtype)
+                                    for r in rows)
+                    for x, r in zip(out, rows):
+                        x[k] = r
+                for x, fill in zip(out, fills):
+                    x[len(batch.idxs):] = fill
+                batch.arrays = out
+        spent = time.perf_counter() - t0
+        stats["prep_s"] += spent
+        stats["artifact_hits"] += self.artifacts.hits - h0
+        stats["artifact_misses"] += self.artifacts.misses - m0
+        return spent
 
-    def _stack_block(self, tasks: Sequence[JoinTask],
-                     prepped: Dict[int, tuple], idxs: Sequence[int],
-                     plen: int) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
-        """Stack one block-sparse bucket's sorted, sentinel-padded sides
-        and its pair lists laid out for ``plen`` rows (``prepped[i]`` is
-        task i's ``(sorted a, sorted b, live pairs)``)."""
-        a_stack = np.stack([self._padded_side(tasks[i][1], self._sentinel,
-                                              sorted_arr=prepped[i][0])
-                            for i in idxs])
-        b_stack = np.stack([self._padded_side(tasks[i][2], -self._sentinel,
-                                              sorted_arr=prepped[i][1])
-                            for i in idxs])
-        p_stack = np.stack([self._ops.pack_pairs_np(prepped[i][2], plen)
-                            for i in idxs])
-        return a_stack, b_stack, p_stack
+    def _rows_dense(self, tasks: Sequence[JoinTask], i: int
+                    ) -> Tuple[np.ndarray, np.ndarray]:
+        """Task ``i``'s dense-grid inputs: both coordinate sets padded to
+        BLOCK (±sentinel fill), coordinate-major (d, N)."""
+        _, a, b, _ = tasks[i]
+        return (self._padded_side(a, self._sentinel),
+                self._padded_side(b, -self._sentinel))
+
+    def _rows_block(self, tasks: Sequence[JoinTask],
+                    prepped: Dict[int, tuple], plen: int, i: int
+                    ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+        """Task ``i``'s block-sparse inputs: its sorted, sentinel-padded
+        sides and its pair list laid out for ``plen`` rows
+        (``prepped[i]`` is the task's ``(sorted a, sorted b, live
+        pairs)``)."""
+        _, a, b, _ = tasks[i]
+        a_s, b_s, pairs = prepped[i]
+        return (self._padded_side(a, self._sentinel, sorted_arr=a_s),
+                self._padded_side(b, -self._sentinel, sorted_arr=b_s),
+                self._ops.pack_pairs_np(pairs, plen))
 
     def _grouped(self, node: Optional[int], same: bool, idxs: Sequence[int],
-                 fn_key: tuple, stack: Callable[[List[int]], tuple]
+                 fn_key: tuple, rows: Callable[[int], tuple]
                  ) -> List[PreparedBatch]:
         """One bucket's batches: its tasks cut into groups of
-        ``ops.GROUP``, each stacked by ``stack`` and padded to that depth
-        with inert elements (sentinel coordinates, an all-invalid pair
-        list) that the kernel loop never reaches."""
+        ``ops.GROUP``, unstacked."""
         depth = self._ops.GROUP
-        fills = (self._sentinel, -self._sentinel, 0)
-        out = []
-        for s in range(0, len(idxs), depth):
-            part = list(idxs[s:s + depth])
-            arrays = tuple(
-                np.concatenate([x, np.full((depth - len(part),)
-                                           + x.shape[1:], fill, x.dtype)])
-                for x, fill in zip(stack(part), fills))
-            out.append(PreparedBatch(node=node, same=same, idxs=part,
-                                     arrays=arrays, fn_key=fn_key))
-        return out
+        return [PreparedBatch(node=node, same=same,
+                              idxs=list(idxs[s:s + depth]), fn_key=fn_key,
+                              rows=rows)
+                for s in range(0, len(idxs), depth)]
 
     def _batches_dense(self, tasks: Sequence[JoinTask], by_node: bool
                        ) -> Tuple[List[PreparedBatch], Dict[str, int]]:
@@ -315,7 +350,7 @@ class PallasJoinExecutor:
             total += (na // self._block) * (nb // self._block) * len(idxs)
             batches += self._grouped(
                 node, same, idxs, ("dense", same, na, nb),
-                lambda part: self._stack_dense(tasks, part))
+                functools.partial(self._rows_dense, tasks))
         return batches, {"block_pairs_total": total,
                          "block_pairs_evaluated": total}
 
@@ -352,8 +387,7 @@ class PallasJoinExecutor:
             same, na, nb, plen = key[-4:]
             batches += self._grouped(
                 node, same, idxs, ("block", same, na, nb, plen),
-                functools.partial(self._stack_block, tasks, prepped,
-                                  plen=plen))
+                functools.partial(self._rows_block, tasks, prepped, plen))
         return batches, {"block_pairs_total": total,
                          "block_pairs_evaluated": evaluated}
 
@@ -364,9 +398,9 @@ class PallasJoinExecutor:
         """Run one prepared batch through its memoized jitted entry;
         returns the ``(ops.GROUP,)`` match-count device array, whose
         first ``len(batch.idxs)`` entries are the batch's tasks.
-        ``arrays`` overrides ``batch.arrays`` with device-placed copies
-        (the mesh backend pins them to the executing node's device
-        first)."""
+        ``arrays`` are device-placed copies of the batch's stacks (the
+        mesh backend's, on the executing node's device); without them
+        the batch must have been stacked (:meth:`stack`)."""
         key = batch.fn_key + (int(eps), self.interpret)
         fn = self._fn_cache.get(key)
         if fn is None:
@@ -380,11 +414,13 @@ class PallasJoinExecutor:
                   len(batch.idxs))
 
     def count_pairs(self, tasks: Sequence[JoinTask], eps: int) -> List[int]:
-        """Per-task match counts via bucketed batched kernel dispatch;
+        """Per-task match counts via bucketed batched kernel dispatch
+        (every batch is stacked: there is no pinned device cache here);
         records the query's block-pair counters, prep/dispatch split,
         and artifact hit/miss deltas in ``last_stats``."""
         counts = [0] * len(tasks)
         batches, stats = self.iter_batches(tasks, eps)
+        self.stack(batches, stats)
         t0 = time.perf_counter()
         with self.tracer.span("dispatch", batches=len(batches)):
             for batch in batches:

@@ -8,7 +8,7 @@ The span taxonomy instrumented across the repo (ARCHITECTURE.md
   ``workload`` > ``batch`` / ``query`` > ``query.rewrite``,
   ``plan.scan`` (> ``scan.read``), ``plan.join``, ``policy.evict``,
   ``policy.place``, ``policy.replicate``, ``device.sync``, ``gather``,
-  ``ship``, ``prep``, ``dispatch`` > ``dispatch.lookup``,
+  ``ship``, ``prep``, ``dispatch`` > ``dispatch.lookup``, ``prep.stack``,
   ``dispatch.put``, ``dispatch.launch``, ``dispatch.wait``,
   ``dispatch.readback`` — plus ``recover`` around a simulated
   node-failure round.

@@ -201,6 +201,85 @@ def test_executor_artifact_reuse_with_views():
         assert ex.last_stats["artifact_misses"] == 0, mode
 
 
+def reference_rows(mode, a, b, same, eps):
+    """Task rows built straight from the kernel helpers, independent of
+    the executor: both sides padded (sorted first on the block grid),
+    plus the packed live pair list there."""
+    from repro.kernels.simjoin import ops, prune
+    from repro.kernels.simjoin.simjoin import BLOCK, SENTINEL
+    if mode == "dense":
+        return (ops.pad_cm_np(a, SENTINEL), ops.pad_cm_np(b, -SENTINEL))
+    a_s = prune.spatial_sort(a)
+    b_s = a_s if same else prune.spatial_sort(b)
+    pairs, _ = prune.build_block_pairs(a_s, b_s, BLOCK, eps, same)
+    return (ops.pad_cm_np(a_s, SENTINEL), ops.pad_cm_np(b_s, -SENTINEL),
+            ops.pack_pairs_np(pairs, prune.padded_pair_len(len(pairs))))
+
+
+@pytest.mark.parametrize("same", [False, True])
+@pytest.mark.parametrize("mode", ["dense", "block"])
+def test_stacks_equal_stack_then_pad(mode, same):
+    """Grouping builds no arrays; stacking on demand writes bytes equal
+    to ``np.stack`` of the rows followed by the inert ``np.full`` pad,
+    for a full group and a partial one."""
+    from repro.kernels.simjoin import ops
+    from repro.kernels.simjoin.simjoin import SENTINEL
+    rng = np.random.default_rng(23)
+    eps = 40
+    sides = [(uniform_coords(rng, int(rng.integers(40, 128))),
+              uniform_coords(rng, int(rng.integers(40, 128))))
+             for _ in range(ops.GROUP + 3)]
+    tasks = [(0, a, a if same else b, same) for a, b in sides]
+    ex = PallasJoinExecutor(prune=mode)
+    batches, stats = ex.iter_batches(tasks, eps)
+    assert sorted(len(b.idxs) for b in batches) == [3, ops.GROUP]
+    assert all(b.arrays is None for b in batches)
+    ex.stack(batches, stats)
+    for batch in batches:
+        rows = [reference_rows(mode, *tasks[i][1:], eps)
+                for i in batch.idxs]
+        pad = ops.GROUP - len(batch.idxs)
+        for x, parts, fill in zip(batch.arrays, zip(*rows),
+                                  (SENTINEL, -SENTINEL, 0)):
+            ref = np.stack(parts)
+            want = np.concatenate(
+                [ref, np.full((pad,) + ref.shape[1:], fill, ref.dtype)])
+            assert x.dtype == want.dtype and x.shape == want.shape
+            assert x.tobytes() == want.tobytes()
+        assert len(batch.arrays) == (2 if mode == "dense" else 3)
+
+
+@pytest.mark.parametrize("mode, cold, warm", [
+    # dense: every lookup happens at stack time — padded a/b of the
+    # cross task (each computing its sorted side), then the self-join's
+    # padded a (hit) and padded b (computed from the sorted hit).
+    ("dense", (2, 5), (4, 0)),
+    # block: grouping looks up sorted sides and pair lists (1 hit, 4
+    # misses), stacking the padded sides (4 hits, 3 misses).
+    ("block", (5, 7), (9, 0))])
+def test_count_pairs_counts_stack_time_artifact_lookups(mode, cold, warm):
+    """``count_pairs`` stacks every batch and reports the artifact
+    hits/misses of all its lookups, stack time included: exactly the
+    cache's own counters' growth, and the deltas that stacking every
+    batch up front gave."""
+    rng = np.random.default_rng(9)
+    a = clustered_coords(rng, 900)
+    b = clustered_coords(rng, 500)
+    q = Box((0, 0, 0), tuple([60_000] * 3))
+    cb = Box((0, 0, 0), (50_100, 50_100, 50_100))
+    ex = PallasJoinExecutor(prune=mode)
+    va, vb = ex.artifacts.view(1, cb, q, a), ex.artifacts.view(2, cb, q, b)
+    tasks = [(0, va, vb, False), (1, va, va, True)]
+    want = NumpyJoinExecutor(count_similar_pairs_np).count_pairs(tasks, 35)
+    for expect in (cold, warm):
+        h0, m0 = ex.artifacts.hits, ex.artifacts.misses
+        assert ex.count_pairs(tasks, 35) == want
+        got = (ex.last_stats["artifact_hits"],
+               ex.last_stats["artifact_misses"])
+        assert got == (ex.artifacts.hits - h0, ex.artifacts.misses - m0)
+        assert got == expect
+
+
 def test_bitmap_eps0_and_duplicate_parity():
     """The eps=0 edge: duplicated cells are the only matches, and the
     box prune's distance bound is exact there — they must count

@@ -194,6 +194,106 @@ def test_pinned_cap_below_one_query_keeps_counts_exact(files, catalog,
                                                    same=True)
 
 
+def spy_stacks(cl):
+    """Count the batches the executor stacks, with ``[total]``: every
+    batch handed to ``stack`` must still be unstacked."""
+    total = [0]
+    orig = cl.backend.executor.stack
+
+    def stack(batches, stats):
+        assert all(b.arrays is None for b in batches)
+        total[0] += len(batches)
+        return orig(batches, stats)
+    cl.backend.executor.stack = stack
+    return total
+
+
+def run_counting_stacks(cl, queries):
+    """Run ``queries``; each query's executed record with the number of
+    batches it stacked."""
+    total = spy_stacks(cl)
+    out = []
+    for q in queries:
+        before = total[0]
+        e = cl.run_query(q)
+        out.append((e, total[0] - before))
+    return out
+
+
+@pytest.mark.parametrize("telemetry", ["on", "off"])
+def test_stacks_only_batches_no_pinned_stack_serves(catalog, telemetry):
+    cl = make(catalog, telemetry)
+    qs = queries_of(catalog)
+    runs = run_counting_stacks(cl, qs)
+    for e, stacked in runs:
+        assert stacked == e.dispatch_launches - e.dispatch_pinned_hits
+    assert sum(e.dispatch_pinned_hits for e, _ in runs) > 0
+    assert sum(stacked for _, stacked in runs) > 0
+    if telemetry == "on":
+        groups = query_spans(cl.telemetry.tracer)
+        assert len(groups) == len(runs)
+        for (_, spans), (_, stacked) in zip(groups, runs):
+            assert sum(s.args["batches"] for s in spans
+                       if s.name == "prep.stack") == stacked
+    # On a fresh cluster the same query at once again finds every batch
+    # pinned: launches, and not one host stack built.
+    q = next(q for q, (e, _) in zip(qs, runs) if e.dispatch_launches)
+    (first, n_first), (again, n_again) = run_counting_stacks(
+        make(catalog, telemetry), [q, q])
+    assert n_first == first.dispatch_launches > 0
+    assert again.dispatch_launches == first.dispatch_launches
+    assert n_again == 0
+
+
+def test_stack_spans_lie_in_prep_not_dispatch(traced):
+    cl, executed = traced
+    by_id = {s.span_id: s for s in cl.telemetry.tracer.spans}
+
+    def dur(spans, name):
+        return sum(s.end - s.start for s in spans if s.name == name)
+
+    groups = query_spans(cl.telemetry.tracer)
+    assert any(s.name == "prep.stack" for _, spans in groups for s in spans)
+    for (_, spans), e in zip(groups, executed):
+        puts = [s for s in spans if s.name == "dispatch.put"]
+        for s in spans:
+            if s.name != "prep.stack":
+                continue
+            p = by_id[s.parent_id]
+            assert p.name == "dispatch"
+            assert all(s.end <= put.start for put in puts)
+        if e.prep_s is None:
+            continue
+        stack = dur(spans, "prep.stack")
+        assert e.prep_s >= dur(spans, "prep") + stack - 1e-9
+        assert e.dispatch_s <= dur(spans, "dispatch") - stack + 1e-9
+        assert e.dispatch_s >= sum(dur(spans, n) for n in SUBSPANS) - 1e-9
+
+
+@pytest.mark.parametrize("budget", [None, 1])
+@pytest.mark.parametrize("prune", ["block", "dense"])
+def test_counts_exact_when_only_misses_are_stacked(files, catalog, prune,
+                                                   budget):
+    # A budget of one byte pins nothing a later batch can find, so every
+    # batch is stacked; the default budget serves repeats from pinned
+    # stacks. Both count what the numpy reference counts.
+    cl = RawArrayCluster(catalog, FileReader(catalog), N_NODES,
+                         NODE_BUDGET, policy="cost", min_cells=64,
+                         backend="jax_mesh", prune=prune, telemetry="off")
+    if budget is not None:
+        cl.backend._pinned_budget = budget
+    qs = queries_of(catalog)
+    runs = run_counting_stacks(cl, qs)
+    hits = sum(e.dispatch_pinned_hits for e, _ in runs)
+    assert (hits == 0) == (budget is not None)
+    coords = np.unique(np.concatenate([f.coords for f in files]), axis=0)
+    for q, (e, stacked) in zip(qs, runs):
+        assert stacked == e.dispatch_launches - e.dispatch_pinned_hits
+        pts = coords[points_in_box(coords, q.box)]
+        assert e.matches == count_similar_pairs_np(pts, pts, q.eps,
+                                                   same=True)
+
+
 def test_sync_and_gather_nest_under_query(traced):
     cl, executed = traced
     by_id = {s.span_id: s for s in cl.telemetry.tracer.spans}
